@@ -11,6 +11,8 @@ import sys
 import time
 import traceback
 
+from repro.runtime.compile_cache import configure_compile_cache
+
 ALL = [
     "fig5_dataflow_opts",
     "fig6_breakdown",
@@ -44,6 +46,7 @@ def main() -> None:
                          "benchmarks that take one (fig7's quantized-"
                          "storage arm; records BENCH_compression.json)")
     args = ap.parse_args()
+    configure_compile_cache()
     names = args.only.split(",") if args.only else ALL
     failures = []
     for name in names:

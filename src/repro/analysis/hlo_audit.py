@@ -194,7 +194,7 @@ def lower_train_step(run) -> dict[str, str]:
     with pipe.step_context():
         db = pipe._device_batch(u, p, n)
         micro = pipe._micro_value_and_grad.lower(
-            state["params"], *db).compile().as_text()
+            state["params"], pipe.g, *db).compile().as_text()
         # params stand in for grads: same pytree, shapes, dtypes
         update = pipe._apply_update.lower(
             state, state["params"], jnp.float32(1e-3)).compile().as_text()

@@ -23,8 +23,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
 
 def bucket_edges(src: np.ndarray, dst: np.ndarray, n_nodes: int, p: int,
@@ -202,14 +201,18 @@ def make_ring_spmm(mesh, axis, n_local: int, with_coeff: bool = False,
             payload = (q, scale)
         else:
             payload = x
-        acc = jnp.zeros((n_local, x.shape[-1]), x.dtype)
+        # the carry must vary over the ring axes like the permuted
+        # payload it accumulates, or the loop's carry types disagree
+        acc = jax.lax.pcast(jnp.zeros((n_local, x.shape[-1]), x.dtype),
+                            axes, to="varying")
         acc, _ = jax.lax.fori_loop(0, steps, body, (acc, payload))
         return acc
 
     xspec = P(axes if len(axes) > 1 else axes[0], None)
     bspec = P(axes if len(axes) > 1 else axes[0], None, None)
     in_specs = (xspec, bspec, bspec, bspec) + ((bspec,) if with_coeff else ())
-    fn = shard_map(local_fn, mesh=mesh, in_specs=in_specs, out_specs=xspec)
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+                       out_specs=xspec)
 
     if with_coeff:
         def ring(x, src_l, dst_l, mask, coeff):

@@ -40,9 +40,12 @@ def _kernel(ue_ref, cq_ref, scale_ref, radius_ref, out_ref):
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def ann_block_scores_pallas(ue: jax.Array, centroids_q: jax.Array,
                             scale: jax.Array, radius: jax.Array,
-                            interpret: bool = True) -> jax.Array:
+                            interpret: bool | None = None) -> jax.Array:
     """ue: f32[B, D]; centroids_q: i8[nb, D]; scale/radius: f32[nb] ->
-    per-block score upper bounds f32[B, nb]."""
+    per-block score upper bounds f32[B, nb].  interpret=None resolves
+    from the backend (compiled on TPU, interpreter elsewhere)."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     b, d = ue.shape
     nb = centroids_q.shape[0]
     # lane-align the block axis (f32 tiles are 8x128); the user axis

@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import MEM_HBM, CompilerParams
-
 DEFAULT_BAG_BLOCK = 8
 
 
@@ -73,7 +71,7 @@ def embedding_bag_pallas(table: jax.Array, ids: jax.Array, mask: jax.Array,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b_pad // bb,),
-        in_specs=[pl.BlockSpec(memory_space=MEM_HBM)],
+        in_specs=[pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)],
         out_specs=pl.BlockSpec((bb, d), lambda i, *_: (i, 0)),
         scratch_shapes=[pltpu.VMEM((1, d), jnp.float32),
                         pltpu.SemaphoreType.DMA],
@@ -82,7 +80,7 @@ def embedding_bag_pallas(table: jax.Array, ids: jax.Array, mask: jax.Array,
         functools.partial(_kernel, bb=bb, bag_len=bag_len, combiner=combiner),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b_pad, d), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name=f"embedding_bag_{combiner}",

@@ -9,7 +9,8 @@ is a gather-SDDMM ('mul') followed by an edge-aggregation SpMM, and the
 intermediate [E, D] Hadamard matrix is exactly the |E|-sized stream the
 paper's §4 rewrites try to keep off the capacity tier.  This kernel
 fuses the three steps: per destination row block, the two source rows
-of each edge are DMA'd HBM->VMEM (double-buffered so the next edge's
+of each edge are DMA'd HBM->VMEM (their indices, like the row pointers,
+stream through bounded SMEM windows — ``spmm.smem_window``) (double-buffered so the next edge's
 fetch overlaps the current multiply-accumulate), the Hadamard product
 is formed *in VMEM*, and the row-block accumulator is written back to
 HBM exactly once — the [E, D] message matrix never exists in HBM.
@@ -42,36 +43,43 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import MEM_HBM, CompilerParams
-from repro.kernels.spmm import DEFAULT_ROW_BLOCK
+from repro.kernels.spmm import (DEFAULT_ROW_BLOCK, EDGE_CHUNK,
+                                pad_to_chunks, smem_window)
 
 STRUCTURES = ("general", "y_is_dst", "x_eq_y")
 
 
-def _kernel(indptr, x_idx, y_idx, scale, x_hbm, y_hbm, out_ref,
-            x_buf, y_buf, sem_x, sem_y, *, rb: int, slope):
-    blk = pl.program_id(0)
+def _kernel(indptr_hbm, x_idx_hbm, y_idx_hbm, x_hbm, y_hbm, scale_ref,
+            out_ref, ptr_smem, xi_smem, yi_smem, base_smem, x_buf, y_buf,
+            sem_x, sem_y, idx_sem, *, rb: int, slope, chunk: int):
+    ptr = smem_window((indptr_hbm,), (ptr_smem,), base_smem, 0,
+                      (idx_sem.at[0],), chunk=chunk)
+    idx = smem_window((x_idx_hbm, y_idx_hbm), (xi_smem, yi_smem), base_smem,
+                      1, (idx_sem.at[1], idx_sem.at[2]), chunk=chunk)
     out_ref[...] = jnp.zeros_like(out_ref)
+    row0 = pl.program_id(0) * rb
 
     def row_body(r, _):
-        row = blk * rb + r
-        lo = indptr[row]
-        hi = indptr[row + 1]
+        lo = ptr(row0 + r)[0]
+        hi = ptr(row0 + r + 1)[0]
 
-        def dma_pair(e, slot):
-            cx = pltpu.make_async_copy(
-                x_hbm.at[pl.ds(x_idx[e], 1), :], x_buf.at[slot],
-                sem_x.at[slot])
-            cy = pltpu.make_async_copy(
-                y_hbm.at[pl.ds(y_idx[e], 1), :], y_buf.at[slot],
-                sem_y.at[slot])
-            return cx, cy
+        def start(e, slot):
+            xi, yi = idx(e)
+            pltpu.make_async_copy(x_hbm.at[pl.ds(xi, 1), :], x_buf.at[slot],
+                                  sem_x.at[slot]).start()
+            pltpu.make_async_copy(y_hbm.at[pl.ds(yi, 1), :], y_buf.at[slot],
+                                  sem_y.at[slot]).start()
+
+        def wait(slot):
+            # a wait needs only the semaphore and the transfer size
+            pltpu.make_async_copy(x_hbm.at[pl.ds(0, 1), :], x_buf.at[slot],
+                                  sem_x.at[slot]).wait()
+            pltpu.make_async_copy(y_hbm.at[pl.ds(0, 1), :], y_buf.at[slot],
+                                  sem_y.at[slot]).wait()
 
         @pl.when(lo < hi)
         def _warmup():
-            cx, cy = dma_pair(lo, lo % 2)
-            cx.start()
-            cy.start()
+            start(lo, lo % 2)
 
         def edge_body(e, _):
             slot = e % 2
@@ -79,35 +87,33 @@ def _kernel(indptr, x_idx, y_idx, scale, x_hbm, y_hbm, out_ref,
             # next edge's fetch overlaps this edge's multiply-accumulate
             @pl.when(e + 1 < hi)
             def _prefetch():
-                cx, cy = dma_pair(e + 1, (e + 1) % 2)
-                cx.start()
-                cy.start()
+                start(e + 1, (e + 1) % 2)
 
-            cx, cy = dma_pair(e, slot)
-            cx.wait()
-            cy.wait()
+            wait(slot)
             # the Hadamard product lives only in VMEM, never in HBM
             out_ref[r, :] = out_ref[r, :] + x_buf[slot, 0] * y_buf[slot, 0]
             return 0
 
         jax.lax.fori_loop(lo, hi, edge_body, 0)
-        # epilogue on the still-VMEM-resident accumulator row
-        v = out_ref[r, :] * scale[row]
-        if slope is not None:
-            v = jnp.where(v >= 0, v, v * slope)
-        out_ref[r, :] = v
         return 0
 
     jax.lax.fori_loop(0, rb, row_body, 0, unroll=False)
+    # epilogue on the still-VMEM-resident accumulator block
+    v = out_ref[...] * scale_ref[...]
+    if slope is not None:
+        v = jnp.where(v >= 0, v, v * slope)
+    out_ref[...] = v
 
 
 @functools.partial(jax.jit, static_argnames=("n_nodes", "row_block",
-                                             "slope", "interpret"))
+                                             "slope", "edge_chunk",
+                                             "interpret"))
 def hadamard_spmm_pallas(x: jax.Array, y: jax.Array, indptr: jax.Array,
                          x_idx: jax.Array, y_idx: jax.Array, n_nodes: int,
                          scale: jax.Array | None = None,
                          slope: float | None = None,
                          row_block: int = DEFAULT_ROW_BLOCK,
+                         edge_chunk: int = EDGE_CHUNK,
                          interpret: bool | None = None) -> jax.Array:
     """Fused gather-Hadamard-aggregate over a dst-sorted CSR.
 
@@ -116,6 +122,7 @@ def hadamard_spmm_pallas(x: jax.Array, y: jax.Array, indptr: jax.Array,
     x_idx / y_idx: int32[E] per-edge row index into x / y.
     scale: optional f32[n_nodes] per-destination epilogue factor.
     slope: optional leaky-relu negative slope applied after ``scale``.
+    edge_chunk: SMEM window length for the streamed indices.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -126,37 +133,39 @@ def hadamard_spmm_pallas(x: jax.Array, y: jax.Array, indptr: jax.Array,
     rb = row_block
     n_pad = ((n_nodes + rb - 1) // rb) * rb
     pad = n_pad - n_nodes
-    indptr = indptr.astype(jnp.int32)
     if scale is None:
         scale = jnp.ones((n_nodes,), jnp.float32)
-    scale = scale.astype(jnp.float32)
-    if pad:
-        indptr = jnp.concatenate(
-            [indptr, jnp.full((pad,), indptr[-1], jnp.int32)])
-        scale = jnp.concatenate([scale, jnp.zeros((pad,), jnp.float32)])
+    scale = jnp.pad(scale.astype(jnp.float32), (0, pad)).reshape(n_pad, 1)
+    # padded rows repeat the last pointer: they own no edges
+    indptr = jnp.pad(indptr.astype(jnp.int32), (0, pad), mode="edge")
+    indptr = pad_to_chunks(indptr, edge_chunk, indptr[-1])
+    x_idx = pad_to_chunks(x_idx.astype(jnp.int32), edge_chunk, 0)
+    y_idx = pad_to_chunks(y_idx.astype(jnp.int32), edge_chunk, 0)
     d = x.shape[-1]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+    hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)
+    fn = pl.pallas_call(
+        functools.partial(_kernel, rb=rb, slope=slope, chunk=edge_chunk),
         grid=(n_pad // rb,),
-        in_specs=[pl.BlockSpec(memory_space=MEM_HBM),
-                  pl.BlockSpec(memory_space=MEM_HBM)],
-        out_specs=pl.BlockSpec((rb, d), lambda i, *_: (i, 0)),
-        scratch_shapes=[pltpu.VMEM((2, 1, d), jnp.float32),
+        in_specs=[hbm, hbm, hbm, hbm, hbm,
+                  pl.BlockSpec((rb, 1), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((rb, d), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_pad, d), jnp.float32),
+        scratch_shapes=[pltpu.SMEM((edge_chunk,), jnp.int32),
+                        pltpu.SMEM((edge_chunk,), jnp.int32),
+                        pltpu.SMEM((edge_chunk,), jnp.int32),
+                        pltpu.SMEM((2,), jnp.int32),
+                        pltpu.VMEM((2, 1, d), jnp.float32),
                         pltpu.VMEM((2, 1, d), jnp.float32),
                         pltpu.SemaphoreType.DMA((2,)),
-                        pltpu.SemaphoreType.DMA((2,))],
-    )
-    fn = pl.pallas_call(
-        functools.partial(_kernel, rb=rb, slope=slope),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_pad, d), jnp.float32),
-        compiler_params=CompilerParams(
+                        pltpu.SemaphoreType.DMA((2,)),
+                        pltpu.SemaphoreType.DMA((3,))],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="hadamard_spmm",
     )
-    out = fn(indptr, x_idx.astype(jnp.int32), y_idx.astype(jnp.int32),
-             scale, x.astype(jnp.float32), y.astype(jnp.float32))
+    out = fn(indptr, x_idx, y_idx, x.astype(jnp.float32),
+             y.astype(jnp.float32), scale)
     return out[:n_nodes]
 
 

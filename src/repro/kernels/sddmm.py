@@ -28,8 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import MEM_HBM, CompilerParams
-
 DEFAULT_EDGE_BLOCK = 128
 
 
@@ -104,8 +102,8 @@ def sddmm_pallas(op: str, x: jax.Array, y: jax.Array, src: jax.Array,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(e_pad // eb,),
-        in_specs=[pl.BlockSpec(memory_space=MEM_HBM),
-                  pl.BlockSpec(memory_space=MEM_HBM)],
+        in_specs=[pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM),
+                  pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)],
         # streaming store: each out block written exactly once (nt-write analog)
         out_specs=pl.BlockSpec((eb, out_d), lambda i, *_: (i, 0)),
         scratch_shapes=[pltpu.VMEM((1, d), jnp.float32),
@@ -116,7 +114,7 @@ def sddmm_pallas(op: str, x: jax.Array, y: jax.Array, src: jax.Array,
         functools.partial(_kernel, op=op, eb=eb),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((e_pad, out_d), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name=f"sddmm_{op}",

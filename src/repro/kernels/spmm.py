@@ -8,11 +8,15 @@ for the whole contraction and writes it back to HBM exactly once
 the paper measured >20x slowdown).
 
 Structure:
-  edges are pre-sorted by destination (CSR); ``indptr`` and the sorted
-  source indices are scalar-prefetched to SMEM; the message matrix (or,
-  with gather=True, the node-feature matrix) stays in HBM and rows are
-  DMA'd per edge into a small VMEM buffer; the out row-block [RB, D] is
-  the VMEM accumulator.
+  edges are pre-sorted by destination (CSR).  ``indptr`` and the sorted
+  source indices stay in HBM and stream through bounded SMEM windows of
+  ``EDGE_CHUNK`` entries (``smem_window``) that refill whenever the row
+  or edge cursor leaves them; rows and edges ascend across the whole
+  grid, so each window is loaded about once.  SMEM holds a few KiB
+  whatever the graph size.  The
+  message matrix (or, with gather=True, the node-feature matrix) stays
+  in HBM and rows are DMA'd per edge into a small VMEM buffer; the out
+  row-block [RB, D] is the VMEM accumulator.
 
 Reduces: 'sum' (used by NGCF/LightGCN/GCN) and 'max' (generalized SpMM).
 """
@@ -26,43 +30,95 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import MEM_HBM, CompilerParams
-
 DEFAULT_ROW_BLOCK = 8
+EDGE_CHUNK = 2048          # int32 edge indices per SMEM window (8 KiB)
 
 
-def _kernel(indptr, rows_src, x_hbm, out_ref, row_buf, sem,
-            *, reduce: str, rb: int, gather: bool):
-    blk = pl.program_id(0)
+def pad_to_chunks(a, chunk: int, fill):
+    """Pad a 1-D index array to a whole number of SMEM windows, so every
+    window DMA is aligned and in bounds (HBM int32 vectors are tiled in
+    1024-element units)."""
+    extra = -a.shape[0] % chunk
+    return jnp.pad(a, (0, extra), constant_values=fill) if extra else a
+
+
+def smem_window(hbms, smems, base_ref, slot: int, sems, *, chunk: int):
+    """Bounded, chunk-aligned SMEM window over 1-D HBM index arrays.
+
+    Returns ``read(pos) -> tuple of a[pos]`` (one per array; array i
+    copies on DMA semaphore ``sems[i]``).  The window covers
+    ``[base_ref[slot], base_ref[slot] + chunk)`` and refills (one
+    synchronous DMA per array) when ``pos`` falls outside it.  Positions
+    ascend over the whole grid, so the window is initialized once, at
+    grid step 0, persists across steps, and loads each chunk about once.
+    The arrays must be padded to a multiple of ``chunk``."""
+
+    @pl.when(pl.program_id(0) == 0)
+    def _init():
+        base_ref[slot] = -chunk          # forces a load at the first read
+
+    def read(pos):
+        base = base_ref[slot]
+
+        @pl.when((pos < base) | (pos >= base + chunk))
+        def _refill():
+            new = pl.multiple_of((pos // chunk) * chunk, chunk)
+            copies = [pltpu.make_async_copy(h.at[pl.ds(new, chunk)], s, sem)
+                      for h, s, sem in zip(hbms, smems, sems)]
+            for c in copies:
+                c.start()
+            for c in copies:
+                c.wait()
+            base_ref[slot] = new
+
+        off = pos - base_ref[slot]
+        return tuple(s[off] for s in smems)
+
+    return read
+
+
+def _kernel(indptr_hbm, src_hbm, x_hbm, out_ref, ptr_smem, idx_smem,
+            base_smem, row_buf, sem, idx_sem, *, reduce: str, rb: int,
+            gather: bool, chunk: int):
+    ptr = smem_window((indptr_hbm,), (ptr_smem,), base_smem, 0,
+                      (idx_sem.at[0],), chunk=chunk)
+    if gather:
+        src = smem_window((src_hbm,), (idx_smem,), base_smem, 1,
+                          (idx_sem.at[1],), chunk=chunk)
     init = 0.0 if reduce == "sum" else -jnp.inf
     out_ref[...] = jnp.full_like(out_ref, init)
+    row0 = pl.program_id(0) * rb
 
     def row_body(r, _):
-        row = blk * rb + r
-        lo = indptr[row]
-        hi = indptr[row + 1]
+        lo = ptr(row0 + r)[0]
+        hi = ptr(row0 + r + 1)[0]
 
         # double-buffered row DMA: two VMEM row buffers + two
         # semaphores ping-pong over the edge loop, so edge e+1's fetch
         # overlaps edge e's accumulate instead of serializing on one
         # start();wait() pair
-        def dma(e, slot):
-            idx = rows_src[e] if gather else e
-            return pltpu.make_async_copy(x_hbm.at[pl.ds(idx, 1), :],
-                                         row_buf.at[slot], sem.at[slot])
+        def start(e, slot):
+            idx = src(e)[0] if gather else e
+            pltpu.make_async_copy(x_hbm.at[pl.ds(idx, 1), :],
+                                  row_buf.at[slot], sem.at[slot]).start()
+
+        def wait(slot):
+            # a wait needs only the semaphore and the transfer size
+            pltpu.make_async_copy(x_hbm.at[pl.ds(0, 1), :],
+                                  row_buf.at[slot], sem.at[slot]).wait()
 
         @pl.when(lo < hi)
         def _warmup():
-            dma(lo, lo % 2).start()
+            start(lo, lo % 2)
 
         def edge_body(e, _):
             slot = e % 2
 
             @pl.when(e + 1 < hi)
             def _prefetch():
-                dma(e + 1, (e + 1) % 2).start()
+                start(e + 1, (e + 1) % 2)
 
-            dma(e, slot).wait()
+            wait(slot)
             v = row_buf[slot, 0]
             if reduce == "sum":
                 out_ref[r, :] = out_ref[r, :] + v
@@ -70,7 +126,6 @@ def _kernel(indptr, rows_src, x_hbm, out_ref, row_buf, sem,
                 out_ref[r, :] = jnp.maximum(out_ref[r, :], v)
             return 0
 
-        # dynamic bounds (indptr in SMEM): older jax forbids `unroll` here
         jax.lax.fori_loop(lo, hi, edge_body, 0)
         return 0
 
@@ -80,11 +135,13 @@ def _kernel(indptr, rows_src, x_hbm, out_ref, row_buf, sem,
 
 
 @functools.partial(jax.jit, static_argnames=("reduce", "n_nodes", "row_block",
-                                             "gather", "interpret"))
+                                             "gather", "edge_chunk",
+                                             "interpret"))
 def spmm_csr_pallas(reduce: str, values: jax.Array, indptr: jax.Array,
                     src_sorted: jax.Array, n_nodes: int,
                     row_block: int = DEFAULT_ROW_BLOCK,
                     gather: bool = False,
+                    edge_chunk: int = EDGE_CHUNK,
                     interpret: bool | None = None) -> jax.Array:
     """CSR SpMM.
 
@@ -92,6 +149,7 @@ def spmm_csr_pallas(reduce: str, values: jax.Array, indptr: jax.Array,
       node features gathered through ``src_sorted`` (gather=True).
     indptr: int32[n_nodes+1] destination row pointers over dst-sorted edges.
     src_sorted: int32[E] source index per dst-sorted edge (used iff gather).
+    edge_chunk: SMEM window length for the streamed source indices.
     interpret: None resolves from the backend (compiled on TPU,
       interpreter elsewhere), so direct callers bypassing ``kernels.ops``
       don't silently run interpreter-mode Pallas on TPU.
@@ -100,31 +158,40 @@ def spmm_csr_pallas(reduce: str, values: jax.Array, indptr: jax.Array,
         raise ValueError(reduce)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    d = values.shape[-1]
+    n_edges = src_sorted.shape[0] if gather else values.shape[0]
+    if n_edges == 0:
+        # no edges: every row is empty, and both reduces map empty to 0
+        return jnp.zeros((n_nodes, d), jnp.float32)
     rb = row_block
     n_pad = ((n_nodes + rb - 1) // rb) * rb
-    pad = n_pad - n_nodes
-    indptr = indptr.astype(jnp.int32)
-    if pad:
-        indptr = jnp.concatenate([indptr, jnp.full((pad,), indptr[-1], jnp.int32)])
-    d = values.shape[-1]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(n_pad // rb,),
-        in_specs=[pl.BlockSpec(memory_space=MEM_HBM)],
-        out_specs=pl.BlockSpec((rb, d), lambda i, *_: (i, 0)),
-        scratch_shapes=[pltpu.VMEM((2, 1, d), jnp.float32),
-                        pltpu.SemaphoreType.DMA((2,))],
-    )
+    # padded rows repeat the last pointer: they own no edges
+    indptr = jnp.pad(indptr.astype(jnp.int32), (0, n_pad - n_nodes),
+                     mode="edge")
+    indptr = pad_to_chunks(indptr, edge_chunk, indptr[-1])
+    src_sorted = src_sorted.astype(jnp.int32)
+    if gather:
+        src_sorted = pad_to_chunks(src_sorted, edge_chunk, 0)
+    hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)
     fn = pl.pallas_call(
-        functools.partial(_kernel, reduce=reduce, rb=rb, gather=gather),
-        grid_spec=grid_spec,
+        functools.partial(_kernel, reduce=reduce, rb=rb, gather=gather,
+                          chunk=edge_chunk),
+        grid=(n_pad // rb,),
+        in_specs=[hbm, hbm, hbm],
+        out_specs=pl.BlockSpec((rb, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_pad, d), jnp.float32),
-        compiler_params=CompilerParams(
+        scratch_shapes=[pltpu.SMEM((edge_chunk,), jnp.int32),
+                        pltpu.SMEM((edge_chunk,), jnp.int32),
+                        pltpu.SMEM((2,), jnp.int32),
+                        pltpu.VMEM((2, 1, d), jnp.float32),
+                        pltpu.SemaphoreType.DMA((2,)),
+                        pltpu.SemaphoreType.DMA((2,))],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name=f"spmm_{reduce}",
     )
-    out = fn(indptr, src_sorted.astype(jnp.int32), values.astype(jnp.float32))
+    out = fn(indptr, src_sorted, values.astype(jnp.float32))
     return out[:n_nodes]
 
 

@@ -36,6 +36,7 @@ from repro import configs as config_registry
 from repro.api import (DataCfg, Experiment, ExperimentSpec, LoopCfg,
                        ModelCfg, PlanCfg, get_preset)
 from repro.optim import adam
+from repro.runtime.compile_cache import configure_compile_cache
 from repro.runtime.loop import LoopConfig, run_training
 
 PIPELINE_ARCHS = ("lightgcn", "ngcf", "gcn")
@@ -309,6 +310,7 @@ def train_recsys(arch: str, steps: int, ckpt_dir: str, batch: int = 256):
 
 def main():
     args = build_arg_parser().parse_args()
+    configure_compile_cache()
     if args.preset or args.spec or args.arch in PIPELINE_ARCHS:
         run_experiment(spec_from_args(args))
         return

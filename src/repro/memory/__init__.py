@@ -20,7 +20,8 @@ entry is ``repro.pipeline.plan.build_train_plan``.
 """
 from repro.memory.cache import CacheStats, HotRowCache
 from repro.memory.executor import (HostResident, QuantizedHostResident,
-                                   TieredExecutor, memory_kind_sharding)
+                                   TieredExecutor, like_placement,
+                                   memory_kind_sharding, on_device)
 from repro.memory.policies import (Placement, PlacementPolicy, Plan,
                                    get_policy, place_exact, place_greedy,
                                    policy_names, register_policy)
@@ -37,5 +38,6 @@ __all__ = [
     "Placement", "Plan", "PlacementPolicy", "get_policy",
     "register_policy", "policy_names", "place_greedy", "place_exact",
     "TieredExecutor", "HostResident", "QuantizedHostResident",
-    "memory_kind_sharding", "HotRowCache", "CacheStats",
+    "memory_kind_sharding", "on_device", "like_placement",
+    "HotRowCache", "CacheStats",
 ]

@@ -5,10 +5,14 @@ a ``pinned_host`` memory kind (CPU CI) a host demotion changed nothing
 but a ``describe()`` string.  The executor gives every backend a real
 slow tier:
 
-  * **memory-kind path** (TPU): a demoted leaf is ``device_put`` onto
-    its tier's JAX ``memory_kind`` — XLA then streams it over the host
-    link on access, which is exactly the traffic the cost model prices.
-    ``fetch``/``commit`` are no-ops here.
+  * **memory-kind path** (backends that list the tier's memory kind,
+    e.g. ``pinned_host`` on TPU): a demoted leaf is ``device_put`` onto
+    its tier's JAX ``memory_kind`` and the jitted step takes it there
+    as an argument.  The step moves it into device memory itself
+    (``on_device``) and writes updated leaves back to their tier
+    (``like_placement``), so the host link carries exactly the traffic
+    the cost model prices.  ``fetch`` is a no-op here and ``commit``
+    only re-asserts the placement.
   * **host-store path** (everything else): a demoted leaf's bytes are
     committed to host memory as a numpy buffer — it genuinely leaves
     the device buffer pool.  Each step the executor *fetches* demoted
@@ -58,18 +62,37 @@ from repro.optim.compression import dequantize_rows_int8, quantize_rows_int8
 
 def memory_kind_sharding(kind: str | None):
     """A single-device sharding onto the given memory kind, when the
-    backend exposes one; None otherwise (then the host-store path takes
-    over)."""
+    backend lists one; None otherwise (then the host-store path takes
+    over).  Any other failure propagates: a placement that cannot be
+    made must not fall back to another tier in silence."""
     if kind is None:
         return None
-    try:
-        dev = jax.devices()[0]
-        kinds = {m.kind for m in dev.addressable_memories()}
-        if kind not in kinds:
-            return None
-        return jax.sharding.SingleDeviceSharding(dev, memory_kind=kind)
-    except Exception:  # noqa: BLE001 — backends without memories API
+    dev = jax.devices()[0]
+    if kind not in {m.kind for m in dev.addressable_memories()}:
         return None
+    return jax.sharding.SingleDeviceSharding(dev, memory_kind=kind)
+
+
+def _in_host_memory(x) -> bool:
+    space = getattr(jax.typeof(x), "memory_space", None)
+    return space == jax.memory.Space.Host
+
+
+def on_device(tree):
+    """Inside a jitted step: leaves living in host memory (the
+    memory-kind slow tier) -> device memory, in the program's own H2D
+    stream; every other leaf passes through."""
+    return jax.tree.map(
+        lambda x: jax.device_put(x, jax.memory.Space.Device)
+        if _in_host_memory(x) else x, tree)
+
+
+def like_placement(new, old):
+    """Inside a jitted step: each leaf of ``new`` goes back to host
+    memory where the matching leaf of ``old`` came from it."""
+    return jax.tree.map(
+        lambda n, o: jax.device_put(n, jax.memory.Space.Host)
+        if _in_host_memory(o) else n, new, old)
 
 
 class HostResident:
@@ -141,6 +164,8 @@ class TieredExecutor:
         self.caches: dict[str, object] = {}
         # host-store leaves currently demoted (by profile name)
         self._host_names: set[str] = set()
+        # memory-kind leaves currently demoted: name -> their sharding
+        self._kind_sharding: dict[str, object] = {}
         # int8 buffers for quantized host-store tables: name -> (q, scale)
         self._int8: dict[str, tuple[np.ndarray, np.ndarray]] = {}
 
@@ -194,17 +219,24 @@ class TieredExecutor:
         tier's memory kind when the backend has it, the host store
         otherwise.  Returns (state, n_offloaded)."""
         self._host_names.clear()
+        self._kind_sharding.clear()
         self._int8.clear()
         moved = 0
+
+        fast = memory_kind_sharding(self.topology.fast.memory_kind)
 
         def place_leaf(name, leaf):
             nonlocal moved
             tier = self._demoted_tier(name)
             if tier is None:
-                return leaf
+                # committed like the demoted leaves, so the first step
+                # sees the same argument placements as every later one
+                # (a mix of committed and uncommitted inputs recompiles)
+                return leaf if fast is None else jax.device_put(leaf, fast)
             sh = memory_kind_sharding(tier.memory_kind)
             moved += 1
             if sh is not None:
+                self._kind_sharding[name] = sh
                 return jax.device_put(leaf, sh)
             self._host_names.add(name)
             return self._store(name, leaf)
@@ -229,12 +261,19 @@ class TieredExecutor:
         """Write demoted leaves' updated bytes back to the host store
         (the slow tier owns them between steps; quantized tables
         re-quantize here, so the carried state is always the int8
-        round-trip).  Identity when nothing is host-resident."""
-        if not self._host_names:
+        round-trip).  Memory-kind leaves are re-placed on their tier — a
+        no-op when the step already wrote them there.  Identity when
+        nothing is demoted."""
+        if not self._host_names and not self._kind_sharding:
             return state
-        return self._walk(
-            state, lambda name, leaf:
-            self._store(name, leaf) if name in self._host_names else leaf)
+
+        def commit_leaf(name, leaf):
+            if name in self._host_names:
+                return self._store(name, leaf)
+            sh = self._kind_sharding.get(name)
+            return leaf if sh is None else jax.device_put(leaf, sh)
+
+        return self._walk(state, commit_leaf)
 
     # ------------------------------------------------------------ serving
     def host_table(self, name: str, table):

@@ -36,7 +36,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.optim.compression import (dequantize_int8,
@@ -164,10 +163,10 @@ class GradCompressor:
 
             spec_full = P(*([None] * g.ndim))
             spec_stack = P(ax, *([None] * g.ndim))
-            fn = shard_map(local, mesh=mesh,
-                           in_specs=(spec_full, spec_stack, P(ax, None)),
-                           out_specs=(spec_full, spec_stack),
-                           check_rep=False)
+            fn = jax.shard_map(local, mesh=mesh,
+                               in_specs=(spec_full, spec_stack, P(ax, None)),
+                               out_specs=(spec_full, spec_stack),
+                               check_vma=False)
             combined, r = fn(g, e, keys)
             outs.append(combined)
             resids.append(r)

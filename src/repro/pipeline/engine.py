@@ -41,7 +41,8 @@ from repro.core.large_batch import LargeBatchSchedule
 from repro.data.loader import EdgeLoader
 from repro.data.synth import InteractionData
 from repro.dist.hints import sharding_hints
-from repro.memory import TieredExecutor, get_topology
+from repro.memory import (TieredExecutor, get_topology, like_placement,
+                          on_device)
 from repro.optim import adam, sgd
 from repro.pipeline.plan import TrainPlan, build_train_plan
 from repro.pipeline.registry import get_model
@@ -117,6 +118,9 @@ class Pipeline:
                               train.n_items, impl=cfg.impl, shard=self.shard,
                               hadamard=cfg.hadamard)
         self.shard = self.g.shard
+        # ring cubes exist before the planner sizes them and before the
+        # first trace, so the jitted step takes them as arguments
+        self.g.build_ring(self.spec.ring_ops)
         impl = self.g.impl                     # kernel impl: pallas | xla
         self.n_items = train.n_items
 
@@ -169,26 +173,34 @@ class Pipeline:
         n_layers = cfg.n_layers
         l2 = cfg.l2
         spec = self.spec
-        g = self.g
 
+        # the graph is an argument (a pytree of index arrays), never a
+        # closed-over constant: programs stay small at millions of edges
         @jax.jit
-        def micro_value_and_grad(params, users, pos, neg):
+        def micro_value_and_grad(params, g, users, pos, neg):
+            params = on_device(params)       # memory-kind slow tier
             def loss_fn(p):
                 ue, ie = spec.forward(p, g, n_layers)
                 return bpr.bpr_loss(ue, ie, users, pos, neg, l2=l2)
             return jax.value_and_grad(loss_fn)(params)
 
+        self._forward = jax.jit(
+            lambda params, g: spec.forward(on_device(params), g, n_layers))
+
         compressor = self.compressor
 
         @jax.jit
         def apply_update(state, grads, lr):
+            placed = state
+            state = on_device(state)
             out = {}
             if compressor is not None:
                 grads, out["comp"] = compressor(grads, state["comp"])
             p, o = self.opt.update(grads, state["opt"], state["params"],
                                    lr=lr)
             out["params"], out["opt"] = p, o
-            return out
+            # updated slow-tier leaves go back where they came from
+            return like_placement(out, placed)
 
         self._micro_value_and_grad = micro_value_and_grad
         self._apply_update = apply_update
@@ -295,7 +307,8 @@ class Pipeline:
             sl = slice(c * mu, min((c + 1) * mu, n))
             w = (sl.stop - sl.start) / n
             loss, grads = self._micro_value_and_grad(
-                params, *self._device_batch(users[sl], pos[sl], neg[sl]))
+                params, self.g,
+                *self._device_batch(users[sl], pos[sl], neg[sl]))
             wl = loss * w
             wg = jax.tree.map(lambda t: t * w, grads)
             loss_sum = wl if loss_sum is None else loss_sum + wl
@@ -397,7 +410,7 @@ class Pipeline:
     # ---------------------------------------------------------------- eval
     def embeddings(self, state):
         """Final (user, item) embeddings for evaluation."""
-        return self.spec.forward(state["params"], self.g, self.cfg.n_layers)
+        return self._forward(state["params"], self.g)
 
     def attach_holdout(self, holdout: InteractionData) -> None:
         """Enable periodic held-out evaluation: sets ``eval_fn`` (which

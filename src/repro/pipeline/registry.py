@@ -40,6 +40,8 @@ class ModelSpec:
     forward: Callable       # (params, g, n_layers) -> (user_emb, item_emb)
     materializes_messages: bool   # [E, embed_dim] edge matrix per layer
     concat_layers: bool = False   # output concatenates all layer embeddings
+    ring_ops: tuple[str, ...] = ("sym", "sym_T")   # ring SpMMs the
+    #                               forward (and its VJP) applies
 
     def out_dim(self, embed_dim: int, n_layers: int) -> int:
         """Final embedding width (drives the planner's per-sample cost)."""
@@ -134,7 +136,8 @@ MODELS = {
     "lightgcn": ModelSpec("lightgcn", _lightgcn_init, _lightgcn_forward,
                           materializes_messages=False),
     "ngcf": ModelSpec("ngcf", _ngcf_init, _ngcf_forward,
-                      materializes_messages=True, concat_layers=True),
+                      materializes_messages=True, concat_layers=True,
+                      ring_ops=("ui", "iu", "ui_T", "iu_T")),
     "gcn": ModelSpec("gcn", _gcn_init, _gcn_forward,
                      materializes_messages=False),
 }

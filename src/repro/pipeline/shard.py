@@ -39,7 +39,7 @@ import math
 
 import jax
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 
 def auto_axes(shape) -> tuple[str, ...]:
@@ -57,7 +57,9 @@ def auto_axes(shape) -> tuple[str, ...]:
 @functools.lru_cache(maxsize=None)
 def _mesh_for(shape: tuple[int, ...], axes: tuple[str, ...]):
     """One mesh per (shape, axes) per process — meshes are cheap but
-    building them repeatedly defeats jit caching of shard_mapped fns."""
+    building them repeatedly defeats jit caching of shard_mapped fns.
+    Axes are ``Auto``: the shard layer places inputs and lets GSPMD
+    propagate shardings through the step (``dist.hints``)."""
     n_dev = len(jax.devices())
     need = int(np.prod(shape))
     if need > n_dev:
@@ -65,7 +67,8 @@ def _mesh_for(shape: tuple[int, ...], axes: tuple[str, ...]):
             f"mesh shape {shape} needs {need} devices but only {n_dev} "
             f"are visible; on CPU CI export "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={need}")
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 @dataclasses.dataclass(frozen=True)

@@ -33,8 +33,15 @@ propagation becomes ONE ring SpMM per layer (both directions at once,
 since the unified adjacency is symmetric), and every ring op carries a
 custom VJP that is the transpose-direction ring — the same
 gradients-map-onto-the-same-kernels structure (§4) as the CSR path.
+
+``BipartiteCSR`` is a pytree: its index arrays (and the ring bucket
+cubes) are the leaves, so a jitted step takes the graph as an argument
+instead of baking millions of indices into the program as constants.
 """
 from __future__ import annotations
+
+import copy
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -51,58 +58,54 @@ def default_impl() -> str:
     return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
-def _make_adj_matmul(indptr_f, src_f, n_dst, indptr_b, src_b, n_src, impl):
-    """out = A x via gather-SpMM; VJP = A^T ct via the reverse CSR."""
-
-    def _run(x):
-        return kops.spmm_csr("sum", x, indptr_f, src_f, n_dst,
-                             gather=True, impl=impl)
-
-    @jax.custom_vjp
-    def matmul(x):
-        return _run(x)
-
-    def fwd(x):
-        return _run(x), None
-
-    def bwd(_, ct):
-        return (kops.spmm_csr("sum", ct, indptr_b, src_b, n_src,
-                              gather=True, impl=impl),)
-
-    matmul.defvjp(fwd, bwd)
-    return matmul
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _adj_matmul(impl, n_dst, n_src, x, fwd, bwd):
+    """out = A x via gather-SpMM; VJP = A^T ct via the reverse CSR.
+    ``fwd``/``bwd``: (indptr, src) of the forward / reverse CSR."""
+    return kops.spmm_csr("sum", x, *fwd, n_dst, gather=True, impl=impl)
 
 
-def _make_edge_agg(indptr, dst_sorted, n_dst, impl):
+def _adj_matmul_fwd(impl, n_dst, n_src, x, fwd, bwd):
+    return _adj_matmul(impl, n_dst, n_src, x, fwd, bwd), bwd
+
+
+def _adj_matmul_bwd(impl, n_dst, n_src, bwd, ct):
+    return (kops.spmm_csr("sum", ct, *bwd, n_src, gather=True, impl=impl),
+            None, None)
+
+
+_adj_matmul.defvjp(_adj_matmul_fwd, _adj_matmul_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _edge_agg(impl, n_dst, values, indptr, dst_sorted):
     """out[v] = sum of edge values into v (values already dst-sorted);
     VJP = ct[dst_e], the SDDMM-copy gather."""
-
-    def _run(values):
-        # src_sorted operand unused when gather=False; pass dst_sorted
-        return kops.spmm_csr("sum", values, indptr, dst_sorted, n_dst,
-                             gather=False, impl=impl)
-
-    @jax.custom_vjp
-    def agg(values):
-        return _run(values)
-
-    def fwd(values):
-        return _run(values), None
-
-    def bwd(_, ct):
-        return (ct[dst_sorted],)
-
-    agg.defvjp(fwd, bwd)
-    return agg
+    # src_sorted operand unused when gather=False; pass dst_sorted
+    return kops.spmm_csr("sum", values, indptr, dst_sorted, n_dst,
+                         gather=False, impl=impl)
 
 
-def _make_hadamard_agg(indptr_f, src_f, dst_f, n_dst, indptr_b, src_b,
-                       n_src, impl):
+def _edge_agg_fwd(impl, n_dst, values, indptr, dst_sorted):
+    return _edge_agg(impl, n_dst, values, indptr, dst_sorted), dst_sorted
+
+
+def _edge_agg_bwd(impl, n_dst, dst_sorted, ct):
+    return ct[dst_sorted], None, None
+
+
+_edge_agg.defvjp(_edge_agg_fwd, _edge_agg_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _hadamard_agg(impl, n_dst, n_src, x, y, fwd, bwd):
     """Fused Hadamard aggregation with a REMATERIALIZING custom VJP.
 
     Forward: out[v] = sum_{e: dst_e = v} x[src_e] * y[v] — one
     ``ops.hadamard_spmm`` call (structure ``y_is_dst``: the second
     factor rides the destination), no [E, D] message matrix.
+    ``fwd`` = (indptr, src, dst) of the forward CSR, ``bwd`` =
+    (indptr, src) of the transpose CSR.
 
     Backward saves only the NODE embeddings (x, y) as residuals and
     recomputes the edge products inside the cotangent kernels instead
@@ -117,28 +120,43 @@ def _make_hadamard_agg(indptr_f, src_f, dst_f, n_dst, indptr_b, src_b,
                — the forward CSR with ct riding the destination
                  (structure ``y_is_dst`` again).
     """
+    return kops.hadamard_spmm(x, y, *fwd, n_dst, structure="y_is_dst",
+                              impl=impl)
 
-    def _run(x, y):
-        return kops.hadamard_spmm(x, y, indptr_f, src_f, dst_f, n_dst,
-                                  structure="y_is_dst", impl=impl)
+
+def _hadamard_agg_fwd(impl, n_dst, n_src, x, y, fwd, bwd):
+    return _hadamard_agg(impl, n_dst, n_src, x, y, fwd, bwd), \
+        (x, y, fwd, bwd)
+
+
+def _hadamard_agg_bwd(impl, n_dst, n_src, res, ct):
+    x, y, fwd, (indptr_b, src_b) = res
+    d_x = kops.hadamard_spmm(ct, y, indptr_b, src_b, src_b, n_src,
+                             structure="x_eq_y", impl=impl)
+    d_y = kops.hadamard_spmm(x, ct, *fwd, n_dst, structure="y_is_dst",
+                             impl=impl)
+    return d_x, d_y, None, None
+
+
+_hadamard_agg.defvjp(_hadamard_agg_fwd, _hadamard_agg_bwd)
+
+
+def _ring_matmul(fwd_fn, bwd_fn):
+    """x_pad -> A x_pad through one ring closure, with the VJP riding the
+    transpose ring.  The bucket arrays are arguments (no cotangent)."""
 
     @jax.custom_vjp
-    def agg(x, y):
-        return _run(x, y)
+    def matmul(x, fwd, bwd):
+        return fwd_fn(x, *fwd)
 
-    def fwd(x, y):
-        return _run(x, y), (x, y)
+    def fwd_rule(x, fwd, bwd):
+        return fwd_fn(x, *fwd), bwd
 
-    def bwd(res, ct):
-        x, y = res
-        d_x = kops.hadamard_spmm(ct, y, indptr_b, src_b, src_b, n_src,
-                                 structure="x_eq_y", impl=impl)
-        d_y = kops.hadamard_spmm(x, ct, indptr_f, src_f, dst_f, n_dst,
-                                 structure="y_is_dst", impl=impl)
-        return d_x, d_y
+    def bwd_rule(bwd, ct):
+        return bwd_fn(ct, *bwd), None, None
 
-    agg.defvjp(fwd, bwd)
-    return agg
+    matmul.defvjp(fwd_rule, bwd_rule)
+    return matmul
 
 
 # ---------------------------------------------------------------- ring
@@ -150,9 +168,11 @@ class _RingGraph:
 
     ``sym`` applies the symmetric adjacency (both edge directions in
     one ring pass); ``ui``/``iu`` apply only the user->item /
-    item->user direction.  Every op resolves its bucket cubes lazily at
-    first trace (LightGCN/GCN only ever build ``sym``; the directional
-    cubes exist only for models that call them, i.e. NGCF).
+    item->user direction; ``*_T`` are their exact transposes.  Each
+    op's bucket cubes (``arrays``, device arrays row-sharded over the
+    mesh) are built on first use, or up front by ``build`` — the
+    pipeline builds the ops its model uses before the first trace, so
+    the cubes reach the jitted step as arguments.
     """
 
     def __init__(self, shard: ShardPlan, user: np.ndarray, item: np.ndarray,
@@ -166,26 +186,23 @@ class _RingGraph:
         self.part = shard.partition(n_users + n_items)
         self._src_ui = np.asarray(user, np.int64)
         self._dst_ui = np.asarray(item, np.int64) + n_users
-        self._fns: dict[str, object] = {}
+        self.arrays: dict[str, tuple] = {}
+        self._fns: dict = {}
 
     def _banded(self) -> bool:
         s = self.shard.ring_steps
         return s is not None and s < self.shard.n_shards
 
-    def _build(self, src: np.ndarray, dst: np.ndarray,
-               n_steps: int | None):
-        """One direction's ring closure: x_pad [n_pad, D] -> A x_pad.
-        The bucket cubes stay host numpy: ``_build`` may first run
-        inside a jit trace (ops resolve lazily), and memoizing arrays
-        device-put during a trace would leak tracers into later
-        traces — numpy closures bake in as constants per compile."""
-        src_l, dst_l, mask, n_local = self._bucket_edges(
-            src, dst, self.part.n_pad, self.shard.n_shards,
-            n_steps=n_steps)
-        fn = self._make_ring_spmm(self.shard.build_mesh(), self.shard.dp,
-                                  n_local, n_steps=n_steps,
-                                  quantize=self.shard.ring_quant)
-        return lambda x: fn(x, src_l, dst_l, mask)
+    def _canon(self, which: str) -> str:
+        """Unbanded, transposes alias the plain reverses (sym is
+        self-adjoint, ui/iu are mutual transposes)."""
+        if self._banded():
+            return which
+        return {"sym_T": "sym", "ui_T": "iu", "iu_T": "ui"}.get(which, which)
+
+    def _steps(self, key: str) -> int | None:
+        """Banded transposes run over the full ring (see ``_edges``)."""
+        return None if key.endswith("_T") else self.shard.ring_steps
 
     def _band_kept(self, src: np.ndarray, dst: np.ndarray):
         """The subset of edges the banded forward actually applies."""
@@ -195,71 +212,61 @@ class _RingGraph:
         keep = rel < self.shard.ring_steps
         return src[keep], dst[keep]
 
-    def _fn(self, which: str):
-        """Memoized ring closures.  ``*_T`` keys are the exact transposes
-        of the banded forwards: the band keeps edge (s, d) by the ring
-        distance of s's owner AHEAD of d's — an asymmetric criterion —
-        so the VJP cannot reuse a banded reverse ring (it would apply a
-        different edge set than A^T).  Instead the transpose buckets the
-        reversed KEPT edges over the full ring.  Unbanded, transposes
-        alias the plain reverses (sym is self-adjoint, ui/iu are mutual
-        transposes)."""
-        if which not in self._fns:
-            s, d = self._src_ui, self._dst_ui
-            sym_s = np.concatenate([s, d])
-            sym_d = np.concatenate([d, s])
-            steps = self.shard.ring_steps
-            if which == "sym":
-                self._fns[which] = self._build(sym_s, sym_d, steps)
-            elif which == "ui":
-                self._fns[which] = self._build(s, d, steps)
-            elif which == "iu":
-                self._fns[which] = self._build(d, s, steps)
-            elif not self._banded():
-                alias = {"sym_T": "sym", "ui_T": "iu", "iu_T": "ui"}
-                self._fns[which] = self._fn(alias[which])
-            else:
-                base = {"sym_T": (sym_s, sym_d), "ui_T": (s, d),
-                        "iu_T": (d, s)}[which]
-                ks, kd = self._band_kept(*base)
-                self._fns[which] = self._build(kd, ks, None)
-        return self._fns[which]
+    def _edges(self, key: str):
+        """(src, dst) of one canonical op.  Banded ``*_T`` keys are the
+        exact transposes of the banded forwards: the band keeps edge
+        (s, d) by the ring distance of s's owner AHEAD of d's — an
+        asymmetric criterion — so the VJP cannot reuse a banded reverse
+        ring (it would apply a different edge set than A^T).  Instead
+        the transpose buckets the reversed KEPT edges over the full
+        ring."""
+        s, d = self._src_ui, self._dst_ui
+        sym_s, sym_d = np.concatenate([s, d]), np.concatenate([d, s])
+        base = {"sym": (sym_s, sym_d), "ui": (s, d), "iu": (d, s)}
+        if key in base:
+            return base[key]
+        ks, kd = self._band_kept(*base[key[:-2]])
+        return kd, ks
 
-    def est_nbytes(self) -> int:
-        """Exact bytes the sym bucket cubes WILL occupy, computed from
-        bucket counts without building them — the planner profiles the
-        graph before any op has traced (cubes resolve lazily), so it
-        needs this analytic size, not the built-so-far total.  The sym
-        set (2E edges) is also a fair proxy for NGCF's ui+iu pair."""
-        p = self.shard.n_shards
-        steps = self.shard.ring_steps if self.shard.ring_steps is not None \
-            else p
-        n_local = self.part.n_local
-        s = np.concatenate([self._src_ui, self._dst_ui])
-        d = np.concatenate([self._dst_ui, self._src_ui])
-        rel = (s // n_local - d // n_local) % p
-        keep = rel < steps
-        dk = (d[keep] // n_local) * steps + rel[keep]
-        counts = np.bincount(dk, minlength=p * steps)
-        emax = max(int(counts.max()) if counts.size else 1, 1)
-        emax = int(np.ceil(emax / 8)) * 8          # bucket_edges pad_multiple
-        return p * steps * emax * (4 + 4 + 1)      # src_l + dst_l + mask
+    def build(self, *keys: str) -> None:
+        """Bucket the given ops' edges and place the cubes on the mesh,
+        each device holding its dst rows.  Concrete even when first
+        reached inside a trace, so nothing traced is memoized."""
+        for key in map(self._canon, keys):
+            if key in self.arrays:
+                continue
+            src_l, dst_l, mask, _ = self._bucket_edges(
+                *self._edges(key), self.part.n_pad, self.shard.n_shards,
+                n_steps=self._steps(key))
+            sh = jax.sharding.NamedSharding(
+                self.shard.build_mesh(),
+                jax.sharding.PartitionSpec(self.shard.dp, None, None))
+            with jax.ensure_compile_time_eval():
+                self.arrays[key] = tuple(jax.device_put(a, sh)
+                                         for a in (src_l, dst_l, mask))
+
+    def _ring_fn(self, n_steps):
+        if n_steps not in self._fns:
+            self._fns[n_steps] = self._make_ring_spmm(
+                self.shard.build_mesh(), self.shard.dp, self.part.n_local,
+                n_steps=n_steps, quantize=self.shard.ring_quant)
+        return self._fns[n_steps]
+
+    def matmul(self, which: str, x):
+        """x_pad [n_pad, D] -> A_which x_pad, VJP = the transpose ring."""
+        fwd, bwd = self._canon(which), self._canon(which + "_T")
+        self.build(fwd, bwd)
+        key = ("vjp", self._steps(fwd), self._steps(bwd))
+        if key not in self._fns:
+            self._fns[key] = _ring_matmul(self._ring_fn(key[1]),
+                                          self._ring_fn(key[2]))
+        return self._fns[key](x, self.arrays[fwd], self.arrays[bwd])
 
     def nbytes(self) -> int:
-        """Planner-facing bucket bytes: the built cubes once any exist
-        (unbanded transpose keys alias their base closure — each counted
-        once), the analytic sym estimate before first trace."""
-        total = 0
-        seen: set[int] = set()
-        for fn in self._fns.values():
-            if id(fn) in seen:
-                continue
-            seen.add(id(fn))
-            for cell in getattr(fn, "__closure__", None) or ():
-                v = cell.cell_contents
-                if hasattr(v, "nbytes"):
-                    total += int(v.nbytes)
-        return max(total, self.est_nbytes())
+        """Bytes of the built bucket cubes (the pipeline builds its
+        model's ops before planning, so the planner sees them all)."""
+        return sum(int(a.nbytes) for arrs in self.arrays.values()
+                   for a in arrs)
 
     # ------------------------------------------------------- lifted ops
     def _lift(self, x, offset: int):
@@ -267,54 +274,30 @@ class _RingGraph:
         z = jnp.zeros((self.part.n_pad, x.shape[-1]), x.dtype)
         return jax.lax.dynamic_update_slice(z, x, (offset, 0))
 
-    def make_sym(self):
-        """x_pad -> A_sym x_pad; VJP = the exact transpose ring (A_sym
-        itself unbanded; the kept-edge transpose when banded).  The
-        closures resolve ``_fn`` lazily at first trace, so bucket cubes
-        only materialize for the ops a model actually uses."""
-
-        @jax.custom_vjp
-        def sym(x):
-            return self._fn("sym")(x)
-
-        sym.defvjp(lambda x: (self._fn("sym")(x), None),
-                   lambda _, ct: (self._fn("sym_T")(ct),))
-        return sym
-
     def _apply(self, which, x, in_off, out_off, n_out):
-        h = self._fn(which)(self._lift(x, in_off))
+        h = self.matmul(which, self._lift(x, in_off))
         return jax.lax.dynamic_slice(h, (out_off, 0), (n_out, x.shape[-1]))
 
-    def make_u2i(self):
-        """x_user [n_users, D] -> [n_items, D]; VJP rides the transpose
-        ring (item->user direction), mirroring the CSR custom VJPs."""
-        nu, ni = self.n_users, self.n_items
+    def u2i(self, x):
+        """x_user [n_users, D] -> [n_items, D]; the VJP rides the
+        transpose ring (item->user direction)."""
+        return self._apply("ui", x, 0, self.n_users, self.n_items)
 
-        @jax.custom_vjp
-        def u2i(x):
-            return self._apply("ui", x, 0, nu, ni)
+    def i2u(self, x):
+        return self._apply("iu", x, self.n_users, 0, self.n_users)
 
-        u2i.defvjp(lambda x: (self._apply("ui", x, 0, nu, ni), None),
-                   lambda _, ct: (self._apply("ui_T", ct, nu, 0, nu),))
-        return u2i
 
-    def make_i2u(self):
-        nu, ni = self.n_users, self.n_items
-
-        @jax.custom_vjp
-        def i2u(x):
-            return self._apply("iu", x, nu, 0, nu)
-
-        i2u.defvjp(lambda x: (self._apply("iu", x, nu, 0, nu), None),
-                   lambda _, ct: (self._apply("iu_T", ct, 0, nu, ni),))
-        return i2u
+_CSR_FIELDS = ("ui_indptr", "ui_src", "ui_dst", "iu_indptr", "iu_src",
+               "iu_dst", "perm_ui_to_iu", "rsqrt_du", "rsqrt_di")
 
 
 class BipartiteCSR:
     """Both CSR directions of a user-item graph + kernel-routed ops.
 
-    Built once per training run (host-side sort); the jnp index arrays
-    are captured as trace-time constants by the jitted train step.
+    Built once per training run (host-side sort).  The graph is a
+    pytree whose leaves are its index arrays and ring cubes, so jitted
+    steps take it as an argument: the program stays small and its cache
+    key carries shapes, not the graph.
 
       agg_u2i(x_user)  -> [n_items, D]   unweighted A^T x
       agg_i2u(x_item)  -> [n_users, D]   unweighted A x
@@ -377,28 +360,17 @@ class BipartiteCSR:
         di = np.bincount(item, minlength=n_items).astype(np.float32)
         self.rsqrt_du = jnp.asarray(1.0 / np.sqrt(np.maximum(du, 1.0)))
         self.rsqrt_di = jnp.asarray(1.0 / np.sqrt(np.maximum(di, 1.0)))
+        if shard is not None and shard.is_sharded:
+            # replicated once on the mesh (the planner prices the CSR at
+            # full size per device), not re-sent with every step
+            rep = jax.sharding.NamedSharding(shard.build_mesh(),
+                                             jax.sharding.PartitionSpec())
+            for f in _CSR_FIELDS:
+                setattr(self, f, jax.device_put(getattr(self, f), rep))
 
         self._ring = None
-        self._ring_sym = None
         if self.spmm == "ring":
             self._ring = _RingGraph(self.shard, user, item, n_users, n_items)
-            self._ring_sym = self._ring.make_sym()
-            self.agg_u2i = self._ring.make_u2i()
-            self.agg_i2u = self._ring.make_i2u()
-        else:
-            self.agg_u2i = _make_adj_matmul(self.ui_indptr, self.ui_src,
-                                            n_items, self.iu_indptr,
-                                            self.iu_src, n_users, self.impl)
-            self.agg_i2u = _make_adj_matmul(self.iu_indptr, self.iu_src,
-                                            n_users, self.ui_indptr,
-                                            self.ui_src, n_items, self.impl)
-        # edge-level aggregation ([E, D] values, dst-sorted) stays on the
-        # node-local kernel path under every dispatch: the values are
-        # already per-edge, so there is no feature block to rotate
-        self.edge_agg_item = _make_edge_agg(self.ui_indptr, self.ui_dst,
-                                            n_items, self.impl)
-        self.edge_agg_user = _make_edge_agg(self.iu_indptr, self.iu_dst,
-                                            n_users, self.impl)
         # fused Hadamard aggregation (NGCF): ring runs fall back to the
         # composed edge_agg route — the rotation schedule owns those
         if hadamard not in ("auto", "fused", "composed"):
@@ -406,12 +378,70 @@ class BipartiteCSR:
                              f"'composed', got {hadamard!r}")
         self.fused_hadamard = hadamard != "composed" \
             and self.spmm != "ring"
-        self.hadamard_agg_item = _make_hadamard_agg(
-            self.ui_indptr, self.ui_src, self.ui_dst, n_items,
-            self.iu_indptr, self.iu_src, n_users, self.impl)
-        self.hadamard_agg_user = _make_hadamard_agg(
-            self.iu_indptr, self.iu_src, self.iu_dst, n_users,
-            self.ui_indptr, self.ui_src, n_items, self.impl)
+
+    # ------------------------------------------------------------ pytree
+    def _tree_flatten(self):
+        ring = self._ring.arrays if self._ring is not None else {}
+        keys = tuple(sorted(ring))
+        leaves = (tuple(getattr(self, f) for f in _CSR_FIELDS),
+                  tuple(ring[k] for k in keys))
+        return leaves, (self, keys)
+
+    @staticmethod
+    def _tree_unflatten(aux, leaves):
+        """A view of the same graph over new leaves (tracers inside a
+        jitted step); host-side structure is shared, not rebuilt."""
+        skeleton, keys = aux
+        g = copy.copy(skeleton)
+        for f, a in zip(_CSR_FIELDS, leaves[0]):
+            setattr(g, f, a)
+        if skeleton._ring is not None:
+            g._ring = copy.copy(skeleton._ring)
+            g._ring.arrays = dict(zip(keys, leaves[1]))
+        return g
+
+    def build_ring(self, ops) -> None:
+        """Build the ring cubes of the given ops (e.g. ``("sym",
+        "sym_T")``) now, so they ride into jitted steps as arguments;
+        a no-op off the ring dispatch."""
+        if self._ring is not None:
+            self._ring.build(*ops)
+
+    # ------------------------------------------------------------ ops
+    def agg_u2i(self, x):
+        if self._ring is not None:
+            return self._ring.u2i(x)
+        return _adj_matmul(self.impl, self.n_items, self.n_users, x,
+                           (self.ui_indptr, self.ui_src),
+                           (self.iu_indptr, self.iu_src))
+
+    def agg_i2u(self, x):
+        if self._ring is not None:
+            return self._ring.i2u(x)
+        return _adj_matmul(self.impl, self.n_users, self.n_items, x,
+                           (self.iu_indptr, self.iu_src),
+                           (self.ui_indptr, self.ui_src))
+
+    # edge-level aggregation ([E, D] values, dst-sorted) stays on the
+    # node-local kernel path under every dispatch: the values are
+    # already per-edge, so there is no feature block to rotate
+    def edge_agg_item(self, values):
+        return _edge_agg(self.impl, self.n_items, values, self.ui_indptr,
+                         self.ui_dst)
+
+    def edge_agg_user(self, values):
+        return _edge_agg(self.impl, self.n_users, values, self.iu_indptr,
+                         self.iu_dst)
+
+    def hadamard_agg_item(self, xu, xi):
+        return _hadamard_agg(self.impl, self.n_items, self.n_users, xu, xi,
+                             (self.ui_indptr, self.ui_src, self.ui_dst),
+                             (self.iu_indptr, self.iu_src))
+
+    def hadamard_agg_user(self, xi, xu):
+        return _hadamard_agg(self.impl, self.n_users, self.n_items, xi, xu,
+                             (self.iu_indptr, self.iu_src, self.iu_dst),
+                             (self.ui_indptr, self.ui_src))
 
     def seen_csr(self) -> tuple[np.ndarray, np.ndarray]:
         """(indptr, items) numpy user-CSR over the train interactions —
@@ -429,9 +459,9 @@ class BipartiteCSR:
         return int(sum(a.size * a.dtype.itemsize for a in arrs))
 
     def ring_nbytes(self) -> int:
-        """Bytes of the ring bucket cubes (built or analytically
-        estimated); 0 off the ring dispatch.  The cubes are dst-sharded
-        over the mesh — each device holds 1/P of them."""
+        """Bytes of the built ring bucket cubes; 0 off the ring
+        dispatch.  The cubes are dst-sharded over the mesh — each
+        device holds 1/P of them."""
         return self._ring.nbytes() if self._ring is not None else 0
 
     def graph_nbytes(self) -> int:
@@ -446,11 +476,11 @@ class BipartiteCSR:
         (symmetric) adjacency: both directions ride a single rotation
         schedule, the distributed analogue of the paper's fused
         NUMA-blocked pass."""
-        if self._ring_sym is not None:
+        if self._ring is not None:
             part = self._ring.part
             z = jnp.concatenate([x_user * self.rsqrt_du[:, None],
                                  x_item * self.rsqrt_di[:, None]], axis=0)
-            h = part.trim(self._ring_sym(part.pad_rows(z)))
+            h = part.trim(self._ring.matmul("sym", part.pad_rows(z)))
             return (h[:self.n_users] * self.rsqrt_du[:, None],
                     h[self.n_users:] * self.rsqrt_di[:, None])
         h_item = self.agg_u2i(x_user * self.rsqrt_du[:, None]) \
@@ -458,3 +488,7 @@ class BipartiteCSR:
         h_user = self.agg_i2u(x_item * self.rsqrt_di[:, None]) \
             * self.rsqrt_du[:, None]
         return h_user, h_item
+
+
+jax.tree_util.register_pytree_node(BipartiteCSR, BipartiteCSR._tree_flatten,
+                                   BipartiteCSR._tree_unflatten)

@@ -2,6 +2,7 @@
 main pytest process keeps its single-device jax config.  Host-side
 pieces of the shard layer (edge bucketing, node partitioning) are
 tested in-process."""
+import pathlib
 import subprocess
 import sys
 import textwrap
@@ -9,13 +10,15 @@ import textwrap
 import numpy as np
 import pytest
 
+REPO = pathlib.Path(__file__).resolve().parents[1]
+
 
 def run_with_devices(code: str, n: int = 8):
     prog = f"import os\nos.environ['XLA_FLAGS'] = " \
            f"'--xla_force_host_platform_device_count={n}'\n" + \
            "import sys; sys.path.insert(0, 'src')\n" + textwrap.dedent(code)
     res = subprocess.run([sys.executable, "-c", prog], capture_output=True,
-                         text=True, timeout=420, cwd="/root/repo")
+                         text=True, timeout=420, cwd=REPO)
     if res.returncode != 0:
         raise AssertionError(f"subprocess failed:\n{res.stdout}\n{res.stderr}")
     return res.stdout
@@ -318,7 +321,7 @@ def test_sharded_fit_matches_single_device_trajectory():
         with pipe.step_context():
             db = pipe._device_batch(u[:16], p[:16], n[:16])
             txt = pipe._micro_value_and_grad.lower(
-                r2.state["params"], *db).compile().as_text()
+                r2.state["params"], pipe.g, *db).compile().as_text()
         from repro.analysis.hlo_audit import assert_clean, expectation_for
         assert_clean(txt, expectation_for(n_shards=4),
                      where="sharded-micro-step")

@@ -53,6 +53,28 @@ def test_spmm_adversarial_shapes(reduce, gather, n, e, d, rb):
     np.testing.assert_array_equal(np.asarray(got)[empty], 0.0)
 
 
+@pytest.mark.parametrize("reduce", ["sum", "max"])
+@pytest.mark.parametrize("gather", [False, True])
+def test_spmm_smem_window_refills(reduce, gather):
+    """Row pointers and source indices stream through SMEM windows; an
+    8-entry window over 300 edges and 41 rows refills dozens of times
+    (windows straddle row and block boundaries) and must match the
+    oracle exactly as the one-window case does."""
+    rng = np.random.default_rng(5)
+    n, e, d = 41, 300, 16
+    src = rng.integers(0, n, e).astype(np.int32)
+    dst = rng.integers(0, n, e).astype(np.int32)
+    indptr, src_sorted, perm = build_csr_by_dst(dst, src, n)
+    values = rng.standard_normal((n, d) if gather else (e, d))
+    values = values.astype(np.float32) if gather \
+        else values.astype(np.float32)[perm]
+    args = (jnp.asarray(values), jnp.asarray(indptr),
+            jnp.asarray(src_sorted), n)
+    got = spmm_csr_pallas(reduce, *args, gather=gather, edge_chunk=8)
+    want = ref.spmm_csr_ref(reduce, *args, gather=gather)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
 # ------------------------------------------------------------------ sddmm
 @pytest.mark.parametrize("op", ["mul", "add", "dot", "copy"])
 @pytest.mark.parametrize("n,e,d,eb", [
@@ -203,6 +225,20 @@ def test_hadamard_spmm_adversarial_shapes(n_src, n_dst, e, d, rb):
     empty = np.diff(indptr) == 0
     assert empty.any()
     np.testing.assert_array_equal(np.asarray(got)[empty], 0.0)
+
+
+def test_hadamard_spmm_smem_window_refills():
+    """Both per-edge index arrays share one refilling SMEM window."""
+    from repro.kernels.hadamard_spmm import hadamard_spmm_pallas
+    indptr, x_idx, y_idx, _, feats = _hadamard_case(21, 23, 19, 200)
+    x, y = feats(23, 16), feats(19, 16)
+    scale = np.random.default_rng(22).standard_normal(19).astype(np.float32)
+    args = (jnp.asarray(x), jnp.asarray(y), jnp.asarray(indptr),
+            jnp.asarray(x_idx), jnp.asarray(y_idx), 19)
+    got = hadamard_spmm_pallas(*args, scale=jnp.asarray(scale), slope=0.2,
+                               row_block=4, edge_chunk=8)
+    want = ref.hadamard_spmm_ref(*args, scale=jnp.asarray(scale), slope=0.2)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
 
 def test_hadamard_spmm_integer_exact():

@@ -1,10 +1,13 @@
 """Launch-layer tests: cell builders lower on a small mesh (subprocess),
 analytic cost model sanity, roofline parsing."""
+import pathlib
 import subprocess
 import sys
 import textwrap
 
 import pytest
+
+REPO = pathlib.Path(__file__).resolve().parents[1]
 
 
 def run_with_devices(code: str, n: int = 8):
@@ -12,7 +15,7 @@ def run_with_devices(code: str, n: int = 8):
            f"'--xla_force_host_platform_device_count={n}'\n" + \
            "import sys; sys.path.insert(0, 'src')\n" + textwrap.dedent(code)
     res = subprocess.run([sys.executable, "-c", prog], capture_output=True,
-                         text=True, timeout=420, cwd="/root/repo")
+                         text=True, timeout=420, cwd=REPO)
     if res.returncode != 0:
         raise AssertionError(f"subprocess failed:\n{res.stdout[-2000:]}\n"
                              f"{res.stderr[-3000:]}")

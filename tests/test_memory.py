@@ -306,3 +306,33 @@ def test_capacity_override_demotes_and_stays_bit_identical():
     n = 3
     assert [tight.step() for _ in range(n)] == \
         [base.step() for _ in range(n)]
+
+
+def test_memory_kind_tier_trains_bit_identically():
+    """On a backend that lists ``pinned_host`` the demoted table lives in
+    host memory between steps; the jitted step moves it to the device
+    and writes it back, and the losses equal the all-fast run's."""
+    import jax
+
+    from repro.memory import memory_kind_sharding
+    if memory_kind_sharding("pinned_host") is None:
+        pytest.skip("backend lists no pinned_host memory")
+    base = _smoke(**{"plan.warmup_epochs": 0})
+    fast = build(base).fit(steps=3).losses
+    run = build(base.override({"memory.pins": {"item_embed": "slow"}}))
+    assert run.pipeline.n_offloaded >= 1
+    kind = lambda: run.state["params"]["item_embed"].sharding.memory_kind
+    assert kind() == "pinned_host"
+    assert run.fit(steps=3).losses == fast
+    assert kind() == "pinned_host"
+    # every step sees the same argument placements: one compile each
+    assert run.pipeline._micro_value_and_grad._cache_size() == 1
+    assert run.pipeline._apply_update._cache_size() == 1
+    assert run.state["params"]["user_embed"].sharding.memory_kind == \
+        jax.devices()[0].default_memory().kind
+
+
+def test_memory_kind_sharding_only_for_listed_kinds():
+    from repro.memory import memory_kind_sharding
+    assert memory_kind_sharding(None) is None
+    assert memory_kind_sharding("no-such-memory") is None

@@ -315,3 +315,22 @@ def test_seek_matches_live_progression():
     np.testing.assert_array_equal(u1, u2)
     np.testing.assert_array_equal(p1, p2)
     np.testing.assert_array_equal(n1, n2)
+
+
+def test_graph_is_a_step_argument_not_a_constant():
+    """The jitted micro step takes the CSR graph as a pytree argument:
+    its lowered program does not grow with the edge count (closed-over
+    index arrays would be inlined as constants)."""
+    def lowered_size(edges):
+        data = synth.generate_bipartite(40, 30, edges, seed=0)
+        pipe = build_pipeline(PipelineConfig(embed_dim=8, microbatch=16,
+                                             target_batch=16), data)
+        leaves = jax.tree.leaves(pipe.g)
+        assert any(leaf.shape == (data.n_edges,) for leaf in leaves)
+        u, p, n = pipe._next_target_batch(1, 0)
+        return len(pipe._micro_value_and_grad.lower(
+            pipe.init_state()["params"], pipe.g,
+            *pipe._device_batch(u, p, n)).as_text())
+
+    small, large = lowered_size(100), lowered_size(1000)
+    assert large < small * 1.05, (small, large)

@@ -224,3 +224,23 @@ def test_max_subgraph_batch_decreases_with_depth():
     b2 = max_subgraph_batch(n_layers=2, **kw)
     b3 = max_subgraph_batch(n_layers=3, **kw)
     assert b1 > b2 > b3  # paper Table 5: exponential shrink with depth
+
+
+def test_compile_cache_dir_from_env_or_checkout(monkeypatch, tmp_path):
+    """A JAX_COMPILATION_CACHE_DIR from the environment is left to JAX;
+    unset, the cache goes to the fixed ``<checkout>/.jax_cache``."""
+    import pathlib
+
+    from repro.runtime.compile_cache import configure_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert configure_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = configure_compile_cache()
+        assert path == str(pathlib.Path(__file__).resolve().parents[1]
+                           / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
