@@ -83,13 +83,13 @@ def test_compressed_psum_int8():
         from repro.optim.compression import compressed_psum_int8
         n_dev = 8
         mesh = jax.make_mesh((n_dev,), ("data",))
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         g = np.random.default_rng(0).standard_normal((n_dev, 256)).astype(np.float32)
         def body(gs, key):
             return compressed_psum_int8(gs[0], key[0], "data")
-        fn = shard_map(body, mesh=mesh, in_specs=(P("data", None), P("data")),
-                       out_specs=P())
+        fn = jax.shard_map(body, mesh=mesh,
+                           in_specs=(P("data", None), P("data")),
+                           out_specs=P())
         keys = jax.random.split(jax.random.PRNGKey(0), n_dev)
         out = fn(jnp.asarray(g), keys)
         want = g.sum(0)
