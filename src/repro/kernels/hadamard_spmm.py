@@ -9,11 +9,12 @@ is a gather-SDDMM ('mul') followed by an edge-aggregation SpMM, and the
 intermediate [E, D] Hadamard matrix is exactly the |E|-sized stream the
 paper's §4 rewrites try to keep off the capacity tier.  This kernel
 fuses the three steps: per destination row block, the two source rows
-of each edge are DMA'd HBM->VMEM (their indices, like the row pointers,
-stream through bounded SMEM windows — ``spmm.smem_window``) (double-buffered so the next edge's
-fetch overlaps the current multiply-accumulate), the Hadamard product
+of each edge are DMA'd HBM->VMEM, double-buffered so the next edge's
+fetch overlaps the current multiply-accumulate; the Hadamard product
 is formed *in VMEM*, and the row-block accumulator is written back to
-HBM exactly once — the [E, D] message matrix never exists in HBM.
+HBM exactly once — the [E, D] message matrix never exists in HBM.  The
+edge indices and row pointers stay in HBM and stream through bounded
+SMEM windows (``spmm.smem_window``).
 
 Write policy: the output keeps the SpMM side's temporal locality
 (destination rows accumulate in VMEM, normal write), while the SDDMM

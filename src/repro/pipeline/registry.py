@@ -40,8 +40,8 @@ class ModelSpec:
     forward: Callable       # (params, g, n_layers) -> (user_emb, item_emb)
     materializes_messages: bool   # [E, embed_dim] edge matrix per layer
     concat_layers: bool = False   # output concatenates all layer embeddings
-    ring_ops: tuple[str, ...] = ("sym", "sym_T")   # ring SpMMs the
-    #                               forward (and its VJP) applies
+    # ring SpMMs the forward and its VJP apply under the ring dispatch
+    ring_ops: tuple[str, ...] = ("sym", "sym_T")
 
     def out_dim(self, embed_dim: int, n_layers: int) -> int:
         """Final embedding width (drives the planner's per-sample cost)."""
