@@ -72,7 +72,9 @@ def _merge_block(ue, ie_blk, block_ids, seen, seen_mask, start,
     """One streamed block: score, mask seen via scatter, top-k merge."""
     b = ue.shape[0]
     blk = ie_blk.shape[0]
-    scores = ue @ ie_blk.T                                  # [B, blk]
+    # fp32 passes: TPU's default f32 matmul rounds inputs to bf16
+    scores = jnp.dot(ue, ie_blk.T,
+                     precision=jax.lax.Precision.HIGHEST)   # [B, blk]
     # canonicalize -0.0 -> +0.0: lax.top_k sorts by IEEE total order
     # (-0.0 < +0.0) while comparison-based dense sorts treat them as a
     # tie — the (score desc, id asc) contract needs one behaviour

@@ -31,7 +31,8 @@ from jax.experimental.pallas import tpu as pltpu
 def _kernel(ue_ref, cq_ref, scale_ref, radius_ref, out_ref):
     ue = ue_ref[...]                                       # [B, D] f32
     cent = cq_ref[...].astype(jnp.float32)                 # [nb, D]
-    dots = jnp.dot(ue, cent.T, preferred_element_type=jnp.float32)
+    dots = jnp.dot(ue, cent.T, precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
     dots = dots * scale_ref[...]                           # [1, nb] bcast
     unorm = jnp.sqrt(jnp.sum(ue * ue, axis=1, keepdims=True))
     out_ref[...] = dots + unorm * radius_ref[...]

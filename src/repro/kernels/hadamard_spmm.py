@@ -52,11 +52,11 @@ STRUCTURES = ("general", "y_is_dst", "x_eq_y")
 
 def _kernel(indptr_hbm, x_idx_hbm, y_idx_hbm, x_hbm, y_hbm, scale_ref,
             out_ref, ptr_smem, xi_smem, yi_smem, base_smem, x_buf, y_buf,
-            sem_x, sem_y, idx_sem, *, rb: int, slope, chunk: int):
+            sem_x, sem_y, idx_sem, *, rb: int, slope):
     ptr = smem_window((indptr_hbm,), (ptr_smem,), base_smem, 0,
-                      (idx_sem.at[0],), chunk=chunk)
+                      (idx_sem.at[0],))
     idx = smem_window((x_idx_hbm, y_idx_hbm), (xi_smem, yi_smem), base_smem,
-                      1, (idx_sem.at[1], idx_sem.at[2]), chunk=chunk)
+                      1, (idx_sem.at[1], idx_sem.at[2]))
     out_ref[...] = jnp.zeros_like(out_ref)
     row0 = pl.program_id(0) * rb
 
@@ -107,14 +107,12 @@ def _kernel(indptr_hbm, x_idx_hbm, y_idx_hbm, x_hbm, y_hbm, scale_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("n_nodes", "row_block",
-                                             "slope", "edge_chunk",
-                                             "interpret"))
+                                             "slope", "interpret"))
 def hadamard_spmm_pallas(x: jax.Array, y: jax.Array, indptr: jax.Array,
                          x_idx: jax.Array, y_idx: jax.Array, n_nodes: int,
                          scale: jax.Array | None = None,
                          slope: float | None = None,
                          row_block: int = DEFAULT_ROW_BLOCK,
-                         edge_chunk: int = EDGE_CHUNK,
                          interpret: bool | None = None) -> jax.Array:
     """Fused gather-Hadamard-aggregate over a dst-sorted CSR.
 
@@ -123,7 +121,6 @@ def hadamard_spmm_pallas(x: jax.Array, y: jax.Array, indptr: jax.Array,
     x_idx / y_idx: int32[E] per-edge row index into x / y.
     scale: optional f32[n_nodes] per-destination epilogue factor.
     slope: optional leaky-relu negative slope applied after ``scale``.
-    edge_chunk: SMEM window length for the streamed indices.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -139,21 +136,21 @@ def hadamard_spmm_pallas(x: jax.Array, y: jax.Array, indptr: jax.Array,
     scale = jnp.pad(scale.astype(jnp.float32), (0, pad)).reshape(n_pad, 1)
     # padded rows repeat the last pointer: they own no edges
     indptr = jnp.pad(indptr.astype(jnp.int32), (0, pad), mode="edge")
-    indptr = pad_to_chunks(indptr, edge_chunk, indptr[-1])
-    x_idx = pad_to_chunks(x_idx.astype(jnp.int32), edge_chunk, 0)
-    y_idx = pad_to_chunks(y_idx.astype(jnp.int32), edge_chunk, 0)
+    indptr = pad_to_chunks(indptr, indptr[-1])
+    x_idx = pad_to_chunks(x_idx.astype(jnp.int32), 0)
+    y_idx = pad_to_chunks(y_idx.astype(jnp.int32), 0)
     d = x.shape[-1]
     hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)
     fn = pl.pallas_call(
-        functools.partial(_kernel, rb=rb, slope=slope, chunk=edge_chunk),
+        functools.partial(_kernel, rb=rb, slope=slope),
         grid=(n_pad // rb,),
         in_specs=[hbm, hbm, hbm, hbm, hbm,
                   pl.BlockSpec((rb, 1), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((rb, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_pad, d), jnp.float32),
-        scratch_shapes=[pltpu.SMEM((edge_chunk,), jnp.int32),
-                        pltpu.SMEM((edge_chunk,), jnp.int32),
-                        pltpu.SMEM((edge_chunk,), jnp.int32),
+        scratch_shapes=[pltpu.SMEM((EDGE_CHUNK,), jnp.int32),
+                        pltpu.SMEM((EDGE_CHUNK,), jnp.int32),
+                        pltpu.SMEM((EDGE_CHUNK,), jnp.int32),
                         pltpu.SMEM((2,), jnp.int32),
                         pltpu.VMEM((2, 1, d), jnp.float32),
                         pltpu.VMEM((2, 1, d), jnp.float32),

@@ -82,7 +82,8 @@ def ann_block_scores_ref(ue: jax.Array, centroids_q: jax.Array,
     candidate whose bound clears the shortlist cut."""
     ue = ue.astype(jnp.float32)
     cent = centroids_q.astype(jnp.float32)
-    dots = jnp.dot(ue, cent.T, preferred_element_type=jnp.float32)
+    dots = jnp.dot(ue, cent.T, precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
     dots = dots * scale[None, :].astype(jnp.float32)
     unorm = jnp.sqrt(jnp.sum(ue * ue, axis=1, keepdims=True))
     return dots + unorm * radius[None, :].astype(jnp.float32)
@@ -114,7 +115,7 @@ def fused_topk_score_ref(ue: jax.Array, table: jax.Array, seen: jax.Array,
         carry_s, carry_i = carry
         start = j * blk
         ie_blk = jax.lax.dynamic_slice_in_dim(table, start, blk, axis=0)
-        scores = ue @ ie_blk.T
+        scores = jnp.dot(ue, ie_blk.T, precision=jax.lax.Precision.HIGHEST)
         scores = jnp.where(scores == 0.0, 0.0, scores)
         ids = start + jax.lax.broadcasted_iota(jnp.int32, (b, blk), 1)
         scores = jnp.where(ids < n_items, scores, neg_inf)

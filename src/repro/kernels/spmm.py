@@ -34,24 +34,25 @@ DEFAULT_ROW_BLOCK = 8
 EDGE_CHUNK = 2048          # int32 edge indices per SMEM window (8 KiB)
 
 
-def pad_to_chunks(a, chunk: int, fill):
+def pad_to_chunks(a, fill):
     """Pad a 1-D index array to a whole number of SMEM windows, so every
     window DMA is aligned and in bounds (HBM int32 vectors are tiled in
     1024-element units)."""
-    extra = -a.shape[0] % chunk
+    extra = -a.shape[0] % EDGE_CHUNK
     return jnp.pad(a, (0, extra), constant_values=fill) if extra else a
 
 
-def smem_window(hbms, smems, base_ref, slot: int, sems, *, chunk: int):
+def smem_window(hbms, smems, base_ref, slot: int, sems):
     """Bounded, chunk-aligned SMEM window over 1-D HBM index arrays.
 
     Returns ``read(pos) -> tuple of a[pos]`` (one per array; array i
     copies on DMA semaphore ``sems[i]``).  The window covers
-    ``[base_ref[slot], base_ref[slot] + chunk)`` and refills (one
+    ``[base_ref[slot], base_ref[slot] + EDGE_CHUNK)`` and refills (one
     synchronous DMA per array) when ``pos`` falls outside it.  Positions
     ascend over the whole grid, so the window is initialized once, at
     grid step 0, persists across steps, and loads each chunk about once.
-    The arrays must be padded to a multiple of ``chunk``."""
+    The arrays must be padded to a multiple of ``EDGE_CHUNK``."""
+    chunk = EDGE_CHUNK
 
     @pl.when(pl.program_id(0) == 0)
     def _init():
@@ -79,12 +80,12 @@ def smem_window(hbms, smems, base_ref, slot: int, sems, *, chunk: int):
 
 def _kernel(indptr_hbm, src_hbm, x_hbm, out_ref, ptr_smem, idx_smem,
             base_smem, row_buf, sem, idx_sem, *, reduce: str, rb: int,
-            gather: bool, chunk: int):
+            gather: bool):
     ptr = smem_window((indptr_hbm,), (ptr_smem,), base_smem, 0,
-                      (idx_sem.at[0],), chunk=chunk)
+                      (idx_sem.at[0],))
     if gather:
         src = smem_window((src_hbm,), (idx_smem,), base_smem, 1,
-                          (idx_sem.at[1],), chunk=chunk)
+                          (idx_sem.at[1],))
     init = 0.0 if reduce == "sum" else -jnp.inf
     out_ref[...] = jnp.full_like(out_ref, init)
     row0 = pl.program_id(0) * rb
@@ -135,13 +136,11 @@ def _kernel(indptr_hbm, src_hbm, x_hbm, out_ref, ptr_smem, idx_smem,
 
 
 @functools.partial(jax.jit, static_argnames=("reduce", "n_nodes", "row_block",
-                                             "gather", "edge_chunk",
-                                             "interpret"))
+                                             "gather", "interpret"))
 def spmm_csr_pallas(reduce: str, values: jax.Array, indptr: jax.Array,
                     src_sorted: jax.Array, n_nodes: int,
                     row_block: int = DEFAULT_ROW_BLOCK,
                     gather: bool = False,
-                    edge_chunk: int = EDGE_CHUNK,
                     interpret: bool | None = None) -> jax.Array:
     """CSR SpMM.
 
@@ -149,7 +148,6 @@ def spmm_csr_pallas(reduce: str, values: jax.Array, indptr: jax.Array,
       node features gathered through ``src_sorted`` (gather=True).
     indptr: int32[n_nodes+1] destination row pointers over dst-sorted edges.
     src_sorted: int32[E] source index per dst-sorted edge (used iff gather).
-    edge_chunk: SMEM window length for the streamed source indices.
     interpret: None resolves from the backend (compiled on TPU,
       interpreter elsewhere), so direct callers bypassing ``kernels.ops``
       don't silently run interpreter-mode Pallas on TPU.
@@ -168,20 +166,19 @@ def spmm_csr_pallas(reduce: str, values: jax.Array, indptr: jax.Array,
     # padded rows repeat the last pointer: they own no edges
     indptr = jnp.pad(indptr.astype(jnp.int32), (0, n_pad - n_nodes),
                      mode="edge")
-    indptr = pad_to_chunks(indptr, edge_chunk, indptr[-1])
+    indptr = pad_to_chunks(indptr, indptr[-1])
     src_sorted = src_sorted.astype(jnp.int32)
     if gather:
-        src_sorted = pad_to_chunks(src_sorted, edge_chunk, 0)
+        src_sorted = pad_to_chunks(src_sorted, 0)
     hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)
     fn = pl.pallas_call(
-        functools.partial(_kernel, reduce=reduce, rb=rb, gather=gather,
-                          chunk=edge_chunk),
+        functools.partial(_kernel, reduce=reduce, rb=rb, gather=gather),
         grid=(n_pad // rb,),
         in_specs=[hbm, hbm, hbm],
         out_specs=pl.BlockSpec((rb, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_pad, d), jnp.float32),
-        scratch_shapes=[pltpu.SMEM((edge_chunk,), jnp.int32),
-                        pltpu.SMEM((edge_chunk,), jnp.int32),
+        scratch_shapes=[pltpu.SMEM((EDGE_CHUNK,), jnp.int32),
+                        pltpu.SMEM((EDGE_CHUNK,), jnp.int32),
                         pltpu.SMEM((2,), jnp.int32),
                         pltpu.VMEM((2, 1, d), jnp.float32),
                         pltpu.SemaphoreType.DMA((2,)),

@@ -93,9 +93,11 @@ def _kernel(table_hbm, seen_hbm, ue_ref, out_s_ref, out_i_ref, blk_buf,
                                    blk_buf, sem)
         cp.start()
         cp.wait()
-        # [blk, tile] = item rows x users (contract the feature dim)
+        # [blk, tile] = item rows x users (contract the feature dim);
+        # fp32 passes on the MXU, as the f32 tables promise
         scores = jax.lax.dot_general(
             blk_buf[...], ue, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
         # -0.0 -> +0.0 before any masking: one total order for ties
         scores = jnp.where(scores == 0.0, 0.0, scores)

@@ -171,8 +171,11 @@ class _RingGraph:
     item->user direction; ``*_T`` are their exact transposes.  Each
     op's bucket cubes (``arrays``, device arrays row-sharded over the
     mesh) are built on first use, or up front by ``build`` — the
-    pipeline builds the ops its model uses before the first trace, so
-    the cubes reach the jitted step as arguments.
+    pipeline builds the ops its model uses (``ModelSpec.ring_ops``)
+    before the first trace, so the cubes reach the jitted step as
+    arguments.  A view unflattened over new leaves (``frozen``: the
+    graph argument inside a jitted step) cannot build: an op missing
+    there raises instead of baking host-bucketed cubes into the program.
     """
 
     def __init__(self, shard: ShardPlan, user: np.ndarray, item: np.ndarray,
@@ -188,6 +191,7 @@ class _RingGraph:
         self._dst_ui = np.asarray(item, np.int64) + n_users
         self.arrays: dict[str, tuple] = {}
         self._fns: dict = {}
+        self.frozen = False
 
     def _banded(self) -> bool:
         s = self.shard.ring_steps
@@ -235,6 +239,11 @@ class _RingGraph:
         for key in map(self._canon, keys):
             if key in self.arrays:
                 continue
+            if self.frozen:
+                raise RuntimeError(
+                    f"ring op {key!r} has no bucket cubes in this graph "
+                    f"view; build it before tracing (BipartiteCSR."
+                    f"build_ring, ModelSpec.ring_ops)")
             src_l, dst_l, mask, _ = self._bucket_edges(
                 *self._edges(key), self.part.n_pad, self.shard.n_shards,
                 n_steps=self._steps(key))
@@ -398,6 +407,7 @@ class BipartiteCSR:
         if skeleton._ring is not None:
             g._ring = copy.copy(skeleton._ring)
             g._ring.arrays = dict(zip(keys, leaves[1]))
+            g._ring.frozen = True
         return g
 
     def build_ring(self, ops) -> None:

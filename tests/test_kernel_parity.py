@@ -15,7 +15,7 @@ import pytest
 from repro.kernels import ref
 from repro.kernels.embedding_bag import embedding_bag_pallas
 from repro.kernels.sddmm import sddmm_pallas
-from repro.kernels.spmm import build_csr_by_dst, spmm_csr_pallas
+from repro.kernels.spmm import EDGE_CHUNK, build_csr_by_dst, spmm_csr_pallas
 from repro.pipeline.sparse import BipartiteCSR
 
 pytestmark = pytest.mark.slow
@@ -56,12 +56,12 @@ def test_spmm_adversarial_shapes(reduce, gather, n, e, d, rb):
 @pytest.mark.parametrize("reduce", ["sum", "max"])
 @pytest.mark.parametrize("gather", [False, True])
 def test_spmm_smem_window_refills(reduce, gather):
-    """Row pointers and source indices stream through SMEM windows; an
-    8-entry window over 300 edges and 41 rows refills dozens of times
-    (windows straddle row and block boundaries) and must match the
-    oracle exactly as the one-window case does."""
+    """Row pointers and source indices stream through SMEM windows of
+    EDGE_CHUNK entries; with more rows and edges than one window holds,
+    both windows refill (straddling row and block boundaries) and must
+    match the oracle exactly as the one-window case does."""
     rng = np.random.default_rng(5)
-    n, e, d = 41, 300, 16
+    n, e, d = EDGE_CHUNK + 300, 3 * EDGE_CHUNK + 77, 8
     src = rng.integers(0, n, e).astype(np.int32)
     dst = rng.integers(0, n, e).astype(np.int32)
     indptr, src_sorted, perm = build_csr_by_dst(dst, src, n)
@@ -70,7 +70,7 @@ def test_spmm_smem_window_refills(reduce, gather):
         else values.astype(np.float32)[perm]
     args = (jnp.asarray(values), jnp.asarray(indptr),
             jnp.asarray(src_sorted), n)
-    got = spmm_csr_pallas(reduce, *args, gather=gather, edge_chunk=8)
+    got = spmm_csr_pallas(reduce, *args, gather=gather)
     want = ref.spmm_csr_ref(reduce, *args, gather=gather)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
@@ -228,15 +228,17 @@ def test_hadamard_spmm_adversarial_shapes(n_src, n_dst, e, d, rb):
 
 
 def test_hadamard_spmm_smem_window_refills():
-    """Both per-edge index arrays share one refilling SMEM window."""
+    """Both per-edge index arrays share one refilling SMEM window; more
+    rows and edges than EDGE_CHUNK make both windows refill."""
     from repro.kernels.hadamard_spmm import hadamard_spmm_pallas
-    indptr, x_idx, y_idx, _, feats = _hadamard_case(21, 23, 19, 200)
-    x, y = feats(23, 16), feats(19, 16)
-    scale = np.random.default_rng(22).standard_normal(19).astype(np.float32)
+    n_src, n_dst, e = 23, 2 * EDGE_CHUNK + 19, 3 * EDGE_CHUNK + 5
+    indptr, x_idx, y_idx, _, feats = _hadamard_case(21, n_src, n_dst, e)
+    x, y = feats(n_src, 8), feats(n_dst, 8)
+    scale = np.random.default_rng(22).standard_normal(n_dst)
+    scale = scale.astype(np.float32)
     args = (jnp.asarray(x), jnp.asarray(y), jnp.asarray(indptr),
-            jnp.asarray(x_idx), jnp.asarray(y_idx), 19)
-    got = hadamard_spmm_pallas(*args, scale=jnp.asarray(scale), slope=0.2,
-                               row_block=4, edge_chunk=8)
+            jnp.asarray(x_idx), jnp.asarray(y_idx), n_dst)
+    got = hadamard_spmm_pallas(*args, scale=jnp.asarray(scale), slope=0.2)
     want = ref.hadamard_spmm_ref(*args, scale=jnp.asarray(scale), slope=0.2)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 

@@ -334,3 +334,29 @@ def test_graph_is_a_step_argument_not_a_constant():
 
     small, large = lowered_size(100), lowered_size(1000)
     assert large < small * 1.05, (small, large)
+
+
+@pytest.mark.parametrize("arch", sorted(MODELS))
+def test_ring_ops_are_built_before_tracing(arch):
+    """Under the ring dispatch the pipeline builds every ring op its
+    model's forward and VJP apply (ModelSpec.ring_ops) before the first
+    trace: lowering the micro step needs no bucketing inside the trace.
+    A graph argument missing an op raises rather than baking
+    host-bucketed cubes into the program."""
+    data, train, _ = _small()
+    pipe = build_pipeline(PipelineConfig(arch=arch, embed_dim=8,
+                                         microbatch=16, target_batch=16,
+                                         impl="ring"), train)
+    assert pipe.g.spmm == "ring"
+    u, p, n = pipe._next_target_batch(1, 0)
+    pipe._micro_value_and_grad.lower(pipe.init_state()["params"], pipe.g,
+                                     *pipe._device_batch(u, p, n))
+
+    g = BipartiteCSR(train.user, train.item, data.n_users, data.n_items,
+                     impl="ring")
+    x = jnp.ones((data.n_users, 8), jnp.float32)
+    u2i = jax.jit(lambda g, x: g.agg_u2i(x))
+    with pytest.raises(RuntimeError, match="before tracing"):
+        u2i(g, x)
+    g.build_ring(("ui", "iu"))
+    np.testing.assert_allclose(u2i(g, x), g.agg_u2i(x))
