@@ -1,0 +1,94 @@
+"""The program's own observability: host spans, device scopes and a
+compile counter.
+
+Nothing here records time.  Spans are ``jax.profiler.TraceAnnotation``s
+on the host and scopes are ``jax.named_scope``s inside traced programs,
+so both land in a profiler trace on the profiler's clock, beside the
+device planes; with no trace running they cost a few microseconds (a
+span) or nothing (a scope: it only names the ops of the compiled
+program in their metadata).
+
+Host spans, each carrying the step number as a ``step`` stat:
+
+  ``train.step``       one engine step (``Pipeline.step_fn``), the
+                       parent of the two below
+  ``train.batch``      drawing the step's target batch: the loader's
+                       microbatches and the negatives (host work the
+                       device waits for unless it overlaps)
+  ``train.loss_sync``  the host blocking on the device for the step's
+                       loss
+
+Device scope: every aggregation over the graph (``pipeline/sparse.py``)
+runs under ``agg/<kind>``, ``kind`` one of ``AGG_KINDS``, in its
+forward and in its custom-VJP backward, so an op's name stack (the
+``tf_op`` of its trace event, the ``op_name`` of its HLO metadata)
+holds ``agg`` as one component whatever transform wraps it.
+
+Counter: one ``jax.monitoring`` listener counts, by function name,
+the lowerings of jitted functions (one per top-level cache miss, whether
+or not the persistent compilation cache then hits) and the backend
+compiles.  ``counters()`` returns a snapshot.
+"""
+from __future__ import annotations
+
+import collections
+import contextlib
+import threading
+
+import jax
+from jax import monitoring
+
+STEP_SPAN = "train.step"
+BATCH_SPAN = "train.batch"
+LOSS_SYNC_SPAN = "train.loss_sync"
+AGG_SCOPE = "agg"
+AGG_KINDS = ("u2i", "i2u", "sym", "edge", "hadamard")
+
+# jax.monitoring duration events -> counter name
+_EVENTS = {
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lowerings",
+    "/jax/core/compile/backend_compile_duration": "compiles",
+}
+
+
+def span(name: str, step: int):
+    """A host span ``name`` carrying ``step`` as a stat."""
+    return jax.profiler.TraceAnnotation(name, step=step)
+
+
+@contextlib.contextmanager
+def agg_scope(kind: str):
+    """Name the ops traced inside ``agg/<kind>``."""
+    if kind not in AGG_KINDS:
+        raise ValueError(f"aggregation kind {kind!r} not in {AGG_KINDS}")
+    with jax.named_scope(AGG_SCOPE), jax.named_scope(kind):
+        yield
+
+
+class _Counter:
+    """Counts of the ``_EVENTS`` by function name, safe across threads
+    (JAX may lower and compile off the main thread)."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._counts = {k: collections.Counter() for k in _EVENTS.values()}
+
+    def __call__(self, event: str, duration: float, **kw) -> None:
+        kind = _EVENTS.get(event)
+        if kind is not None:
+            with self._lock:
+                self._counts[kind][kw.get("fun_name", "")] += 1
+
+    def snapshot(self) -> dict:
+        with self._lock:
+            return {k: dict(c) for k, c in self._counts.items()}
+
+
+_COUNTER = _Counter()
+monitoring.register_event_duration_secs_listener(_COUNTER)
+
+
+def counters() -> dict:
+    """``{"lowerings": {fun_name: n}, "compiles": {fun_name: n}}``
+    since this module was first imported."""
+    return _COUNTER.snapshot()

@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import bpr
 from repro.core.large_batch import LargeBatchSchedule
 from repro.data.loader import EdgeLoader
@@ -313,7 +314,11 @@ class Pipeline:
             wg = jax.tree.map(lambda t: t * w, grads)
             loss_sum = wl if loss_sum is None else loss_sum + wl
             acc = wg if acc is None else jax.tree.map(jnp.add, acc, wg)
-        return float(loss_sum), acc
+        # the host waits here for the device; step_fn advances
+        # _next_step only after the update, so it is this step's number
+        with obs.span(obs.LOSS_SYNC_SPAN, self._next_step):
+            loss = float(loss_sum)
+        return loss, acc
 
     def _next_target_batch(self, k: int, step: int):
         """Drain k loader microbatches into one (u, i+, i-) target batch.
@@ -374,20 +379,24 @@ class Pipeline:
         (``run_training(step_context=...)``), and ``repro.api.Run.step``
         for direct single steps — so the sharded accumulation step sees
         the dp/mesh sharding hints exactly once."""
-        if step != self._next_step:
-            self.seek(step)
-        epoch = self.current_epoch()
-        k = self.plan.microbatches_for_epoch(epoch)
-        users, pos, neg = self._next_target_batch(k, step)
-        # slow-tier leaves stream device-ward once per step (the tables
-        # don't change inside one accumulated batch) through the
-        # executor's double buffer, and the updated bytes stream back
-        # afterwards — identity when nothing is demoted off-device.
-        state = self.executor.fetch(state)
-        loss, grads = self.grads_for_batch(state["params"], users, pos, neg)
-        lr = jnp.float32(self.lr_for_epoch(epoch))
-        self._next_step = step + 1
-        return self.executor.commit(self._apply_update(state, grads, lr)), loss
+        with obs.span(obs.STEP_SPAN, step):
+            if step != self._next_step:
+                self.seek(step)
+            epoch = self.current_epoch()
+            k = self.plan.microbatches_for_epoch(epoch)
+            with obs.span(obs.BATCH_SPAN, step):
+                users, pos, neg = self._next_target_batch(k, step)
+            # slow-tier leaves stream device-ward once per step (the
+            # tables don't change inside one accumulated batch) through
+            # the executor's double buffer, and the updated bytes stream
+            # back afterwards — identity when nothing is demoted off-device.
+            state = self.executor.fetch(state)
+            loss, grads = self.grads_for_batch(state["params"], users, pos,
+                                               neg)
+            lr = jnp.float32(self.lr_for_epoch(epoch))
+            self._next_step = step + 1
+            return (self.executor.commit(self._apply_update(state, grads,
+                                                            lr)), loss)
 
     def on_relayout(self, state):
         """Loop straggler escalation: re-run the planner over the current

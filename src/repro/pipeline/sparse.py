@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.kernels import ops as kops
 from repro.kernels.spmm import build_csr_by_dst
 from repro.pipeline.shard import ShardPlan
@@ -419,39 +420,47 @@ class BipartiteCSR:
 
     # ------------------------------------------------------------ ops
     def agg_u2i(self, x):
-        if self._ring is not None:
-            return self._ring.u2i(x)
-        return _adj_matmul(self.impl, self.n_items, self.n_users, x,
-                           (self.ui_indptr, self.ui_src),
-                           (self.iu_indptr, self.iu_src))
+        with obs.agg_scope("u2i"):
+            if self._ring is not None:
+                return self._ring.u2i(x)
+            return _adj_matmul(self.impl, self.n_items, self.n_users, x,
+                               (self.ui_indptr, self.ui_src),
+                               (self.iu_indptr, self.iu_src))
 
     def agg_i2u(self, x):
-        if self._ring is not None:
-            return self._ring.i2u(x)
-        return _adj_matmul(self.impl, self.n_users, self.n_items, x,
-                           (self.iu_indptr, self.iu_src),
-                           (self.ui_indptr, self.ui_src))
+        with obs.agg_scope("i2u"):
+            if self._ring is not None:
+                return self._ring.i2u(x)
+            return _adj_matmul(self.impl, self.n_users, self.n_items, x,
+                               (self.iu_indptr, self.iu_src),
+                               (self.ui_indptr, self.ui_src))
 
     # edge-level aggregation ([E, D] values, dst-sorted) stays on the
     # node-local kernel path under every dispatch: the values are
     # already per-edge, so there is no feature block to rotate
     def edge_agg_item(self, values):
-        return _edge_agg(self.impl, self.n_items, values, self.ui_indptr,
-                         self.ui_dst)
+        with obs.agg_scope("edge"):
+            return _edge_agg(self.impl, self.n_items, values,
+                             self.ui_indptr, self.ui_dst)
 
     def edge_agg_user(self, values):
-        return _edge_agg(self.impl, self.n_users, values, self.iu_indptr,
-                         self.iu_dst)
+        with obs.agg_scope("edge"):
+            return _edge_agg(self.impl, self.n_users, values,
+                             self.iu_indptr, self.iu_dst)
 
     def hadamard_agg_item(self, xu, xi):
-        return _hadamard_agg(self.impl, self.n_items, self.n_users, xu, xi,
-                             (self.ui_indptr, self.ui_src, self.ui_dst),
-                             (self.iu_indptr, self.iu_src))
+        with obs.agg_scope("hadamard"):
+            return _hadamard_agg(
+                self.impl, self.n_items, self.n_users, xu, xi,
+                (self.ui_indptr, self.ui_src, self.ui_dst),
+                (self.iu_indptr, self.iu_src))
 
     def hadamard_agg_user(self, xi, xu):
-        return _hadamard_agg(self.impl, self.n_users, self.n_items, xi, xu,
-                             (self.iu_indptr, self.iu_src, self.iu_dst),
-                             (self.ui_indptr, self.ui_src))
+        with obs.agg_scope("hadamard"):
+            return _hadamard_agg(
+                self.impl, self.n_users, self.n_items, xi, xu,
+                (self.iu_indptr, self.iu_src, self.iu_dst),
+                (self.ui_indptr, self.ui_src))
 
     def seen_csr(self) -> tuple[np.ndarray, np.ndarray]:
         """(indptr, items) numpy user-CSR over the train interactions —
@@ -487,12 +496,15 @@ class BipartiteCSR:
         schedule, the distributed analogue of the paper's fused
         NUMA-blocked pass."""
         if self._ring is not None:
-            part = self._ring.part
-            z = jnp.concatenate([x_user * self.rsqrt_du[:, None],
-                                 x_item * self.rsqrt_di[:, None]], axis=0)
-            h = part.trim(self._ring.matmul("sym", part.pad_rows(z)))
-            return (h[:self.n_users] * self.rsqrt_du[:, None],
-                    h[self.n_users:] * self.rsqrt_di[:, None])
+            # the whole pass: the lift into the unified node space and
+            # the slices back reshard over the mesh (collective-permutes)
+            with obs.agg_scope("sym"):
+                part = self._ring.part
+                z = jnp.concatenate([x_user * self.rsqrt_du[:, None],
+                                     x_item * self.rsqrt_di[:, None]], axis=0)
+                h = part.trim(self._ring.matmul("sym", part.pad_rows(z)))
+                return (h[:self.n_users] * self.rsqrt_du[:, None],
+                        h[self.n_users:] * self.rsqrt_di[:, None])
         h_item = self.agg_u2i(x_user * self.rsqrt_du[:, None]) \
             * self.rsqrt_di[:, None]
         h_user = self.agg_i2u(x_item * self.rsqrt_di[:, None]) \

@@ -94,3 +94,39 @@ def test_ann_block_scores_compiles(one_chip):
 
     _compile(fn, one_chip, ((256, D), jnp.float32), ((nb, D), jnp.int8),
              ((nb,), jnp.float32), ((nb,), jnp.float32))
+
+
+@pytest.mark.parametrize("arch", ["lightgcn", "ngcf"])
+def test_step_kernels_run_under_the_aggregation_scope(one_chip, arch,
+                                                      monkeypatch):
+    """Every Pallas call of the training step (the SpMMs, and NGCF's
+    fused Hadamard-SpMMs), forward and backward, carries the ``agg``
+    scope in its op metadata: the name stack the device trace gives the
+    op as its ``tf_op``, which ``agg.ms`` reads."""
+    import re
+
+    from repro.data import synth
+    from repro.kernels import ops as kops
+    from repro.pipeline.engine import PipelineConfig, build_pipeline
+
+    data = synth.generate_bipartite(40, 30, 300, seed=0)
+    pipe = build_pipeline(PipelineConfig(arch=arch, embed_dim=D,
+                                         microbatch=16, target_batch=16,
+                                         impl="pallas"), data)
+    monkeypatch.setattr(kops, "_on_tpu", lambda: True)
+
+    def described(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    params = jax.tree.map(described, pipe.init_state()["params"])
+    g = jax.tree.map(described, pipe.g)
+    batch = [jax.ShapeDtypeStruct((16,), jnp.int32, sharding=one_chip)] * 3
+    text = pipe._micro_value_and_grad.lower(params, g, *batch).compile() \
+        .as_text()
+    names = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    under = re.compile(r"(^|/)(\w+\()*agg\)*/")
+    assert names and all(under.search(n) for n in names), names
+    assert any(n.split("/")[1].startswith("transpose(") for n in names)
+    assert any(n.split("/")[1].startswith("jvp(") for n in names)
