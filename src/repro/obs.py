@@ -24,10 +24,13 @@ forward and in its custom-VJP backward, so an op's name stack (the
 ``tf_op`` of its trace event, the ``op_name`` of its HLO metadata)
 holds ``agg`` as one component whatever transform wraps it.
 
-Counter: one ``jax.monitoring`` listener counts, by function name,
+Counters: one ``jax.monitoring`` listener counts, by function name,
 the lowerings of jitted functions (one per top-level cache miss, whether
 or not the persistent compilation cache then hits) and the backend
-compiles.  ``counters()`` returns a snapshot.
+compiles.  Trace-time counts join them through ``count``:
+``spmm_inflight``, keyed by DMA ring depth, counts the traces of the
+Pallas SpMM (``kernels/spmm.py``), one per call shape a program lowers.
+``counters()`` returns a snapshot.
 """
 from __future__ import annotations
 
@@ -49,6 +52,8 @@ _EVENTS = {
     "/jax/core/compile/jaxpr_to_mlir_module_duration": "lowerings",
     "/jax/core/compile/backend_compile_duration": "compiles",
 }
+# counted by the program at trace time (``count``)
+_TRACE_COUNTS = ("spmm_inflight",)
 
 
 def span(name: str, step: int):
@@ -66,18 +71,23 @@ def agg_scope(kind: str):
 
 
 class _Counter:
-    """Counts of the ``_EVENTS`` by function name, safe across threads
-    (JAX may lower and compile off the main thread)."""
+    """Counts of the ``_EVENTS`` by function name and of the
+    ``_TRACE_COUNTS`` by key, safe across threads (JAX may lower and
+    compile off the main thread)."""
 
     def __init__(self):
         self._lock = threading.Lock()
-        self._counts = {k: collections.Counter() for k in _EVENTS.values()}
+        self._counts = {k: collections.Counter()
+                        for k in (*_EVENTS.values(), *_TRACE_COUNTS)}
 
     def __call__(self, event: str, duration: float, **kw) -> None:
         kind = _EVENTS.get(event)
         if kind is not None:
-            with self._lock:
-                self._counts[kind][kw.get("fun_name", "")] += 1
+            self.add(kind, kw.get("fun_name", ""))
+
+    def add(self, kind: str, key) -> None:
+        with self._lock:
+            self._counts[kind][key] += 1
 
     def snapshot(self) -> dict:
         with self._lock:
@@ -88,7 +98,15 @@ _COUNTER = _Counter()
 monitoring.register_event_duration_secs_listener(_COUNTER)
 
 
+def count(kind: str, key) -> None:
+    """One more ``key`` under the trace-time counter ``kind``."""
+    if kind not in _TRACE_COUNTS:
+        raise ValueError(f"counter {kind!r} not in {_TRACE_COUNTS}")
+    _COUNTER.add(kind, key)
+
+
 def counters() -> dict:
-    """``{"lowerings": {fun_name: n}, "compiles": {fun_name: n}}``
-    since this module was first imported."""
+    """``{"lowerings": {fun_name: n}, "compiles": {fun_name: n},
+    "spmm_inflight": {depth: n}}`` since this module was first
+    imported."""
     return _COUNTER.snapshot()

@@ -15,7 +15,8 @@ import pytest
 from repro.kernels import ref
 from repro.kernels.embedding_bag import embedding_bag_pallas
 from repro.kernels.sddmm import sddmm_pallas
-from repro.kernels.spmm import EDGE_CHUNK, build_csr_by_dst, spmm_csr_pallas
+from repro.kernels.spmm import (EDGE_CHUNK, build_csr_by_dst, ring_plan,
+                                spmm_csr_pallas)
 from repro.pipeline.sparse import BipartiteCSR
 
 pytestmark = pytest.mark.slow
@@ -73,6 +74,79 @@ def test_spmm_smem_window_refills(reduce, gather):
     got = spmm_csr_pallas(reduce, *args, gather=gather)
     want = ref.spmm_csr_ref(reduce, *args, gather=gather)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+def _sequential(reduce, rows, indptr):
+    """Each destination row reduced edge by edge in CSR order, in f32."""
+    out = np.zeros((len(indptr) - 1, rows.shape[1]), np.float32)
+    for r in range(len(out)):
+        acc = np.full(rows.shape[1], 0.0 if reduce == "sum" else -np.inf,
+                      np.float32)
+        for e in range(indptr[r], indptr[r + 1]):
+            acc = acc + rows[e] if reduce == "sum" \
+                else np.maximum(acc, rows[e])
+        out[r] = np.where(np.isfinite(acc), acc, 0.0)
+    return out
+
+
+def _ring_stream(case, depth, rng):
+    """(rows, dst per edge, row_block) of an edge stream shaped against
+    the SpMM's DMA ring of ``depth`` slots."""
+    if case == "empty_rows":     # runs of empty rows, over two windows
+        n = 2 * EDGE_CHUNK + 5
+        return n, rng.choice([1, 2, n // 2, n - 3], 3 * depth), None
+    if case == "row_longer_than_ring":
+        return 6, np.repeat([0, 3, 5], [2, 3 * depth + 1, 1]), None
+    if case == "blocks_shorter_than_ring":   # one edge a row, two a block
+        n = 3 * depth + 1
+        return n, rng.permutation(n), 2
+    if case == "one_hub_row":
+        return 9, np.full(3 * depth + 7, 4), None
+    if case == "lookahead_crosses_window":
+        n = 50
+        return n, rng.integers(0, n, 2 * EDGE_CHUNK + 37), None
+    if case == "fewer_edges_than_ring":
+        return 7, rng.integers(0, 7, max(depth // 2, 1)), None
+    raise ValueError(case)
+
+
+@pytest.mark.parametrize("reduce", ["sum", "max"])
+@pytest.mark.parametrize("gather", [False, True])
+@pytest.mark.parametrize("case", [
+    "empty_rows", "row_longer_than_ring", "blocks_shorter_than_ring",
+    "one_hub_row", "lookahead_crosses_window", "fewer_edges_than_ring"])
+def test_spmm_ring_is_the_sequential_reduce(reduce, gather, case):
+    """The row-fetch ring runs over the whole edge stream, across rows,
+    blocks and SMEM windows of row pointers and source indices; each row
+    still reduces its edges one by one in CSR order, so the kernel
+    equals that sequential reduce bit for bit."""
+    d = 8
+    rng = np.random.default_rng(len(case))
+    depth = ring_plan(1, d)[1]
+    n, dst, rb = _ring_stream(case, depth, rng)
+    e = len(dst)
+    assert ring_plan(n, d)[1] == depth
+    src = rng.integers(0, n, e).astype(np.int32)
+    indptr, src_sorted, perm = build_csr_by_dst(dst.astype(np.int32), src, n)
+    sizes = np.diff(indptr)
+    assert {"empty_rows": lambda: (sizes == 0).sum() > n // 2,
+            "row_longer_than_ring": lambda: sizes.max() > depth,
+            "blocks_shorter_than_ring":
+                lambda: sizes[:n - 1].reshape(-1, rb).sum(1).max() < depth,
+            "one_hub_row": lambda: sizes.max() == e,
+            "lookahead_crosses_window":
+                lambda: e % EDGE_CHUNK != 0 and e > EDGE_CHUNK,
+            "fewer_edges_than_ring": lambda: e < depth}[case]()
+    if gather:
+        values = rng.standard_normal((n, d)).astype(np.float32)
+        rows = values[src_sorted]
+    else:
+        values = rng.standard_normal((e, d)).astype(np.float32)[perm]
+        rows = values
+    got = spmm_csr_pallas(reduce, jnp.asarray(values), jnp.asarray(indptr),
+                          jnp.asarray(src_sorted), n, row_block=rb,
+                          gather=gather)
+    np.testing.assert_array_equal(got, _sequential(reduce, rows, indptr))
 
 
 # ------------------------------------------------------------------ sddmm

@@ -37,6 +37,45 @@ def test_the_snapshot_is_a_copy():
     assert "jit(nothing)" not in obs.counters()["lowerings"]
 
 
+def test_the_pallas_spmm_counts_its_ring_depth_once_a_shape():
+    """``spmm_inflight`` counts, by DMA ring depth, one trace of the
+    Pallas SpMM per call shape; the XLA route counts nothing."""
+    from repro.kernels import ops as kops
+    from repro.kernels.spmm import build_csr_by_dst, ring_plan
+
+    rng = np.random.default_rng(0)
+
+    def call(n, e, impl):
+        dst = rng.integers(0, n, e).astype(np.int32)
+        src = rng.integers(0, n, e).astype(np.int32)
+        indptr, src_sorted, _ = build_csr_by_dst(dst, src, n)
+        x = jnp.asarray(rng.standard_normal((n, 8)), jnp.float32)
+        return kops.spmm_csr("sum", x, jnp.asarray(indptr),
+                             jnp.asarray(src_sorted), n, gather=True,
+                             impl=impl)
+
+    depth = ring_plan(11, 8)[1]
+    assert ring_plan(13, 8)[1] == depth
+
+    def inflight():
+        return obs.counters()["spmm_inflight"].get(depth, 0)
+
+    before = inflight()
+    call(11, 29, "pallas")
+    call(11, 29, "pallas")
+    assert inflight() == before + 1
+    call(13, 31, "pallas")
+    assert inflight() == before + 2
+    snap = obs.counters()["spmm_inflight"]
+    call(17, 37, "xla")
+    assert obs.counters()["spmm_inflight"] == snap
+
+
+def test_an_unknown_counter_is_refused():
+    with pytest.raises(ValueError, match="counter"):
+        obs.count("everything", 1)
+
+
 def test_an_unknown_aggregation_kind_is_refused():
     with pytest.raises(ValueError, match="aggregation kind"):
         with obs.agg_scope("everything"):

@@ -18,13 +18,15 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro import obs
 from repro.kernels.ann import ann_block_scores_pallas
 from repro.kernels.hadamard_spmm import hadamard_spmm_pallas
-from repro.kernels.spmm import spmm_csr_pallas
+from repro.kernels.spmm import ring_plan, spmm_csr_pallas
 from repro.kernels.topk_score import fused_topk_score_pallas
 
 N_USERS, N_ITEMS, D = 349_184, 53_248, 128
 E = 1 << 20            # 4 MiB of int32 per index array: 4x the SMEM
+E_CELL = 4_194_228     # lightgcn-m25-train's graph: 2^22 edges, deduplicated
 
 
 @pytest.fixture(scope="module")
@@ -58,6 +60,22 @@ def test_spmm_compiles_past_smem_limit(one_chip, gather):
                                gather=gather, interpret=False),
              one_chip, (vals, jnp.float32), ((N_USERS + 1,), jnp.int32),
              ((E,), jnp.int32))
+
+
+@pytest.mark.parametrize("n_dst,n_src", [(N_ITEMS, N_USERS),
+                                         (N_USERS, N_ITEMS)])
+def test_spmm_ring_compiles_at_the_training_cells_shapes(one_chip, n_dst,
+                                                         n_src):
+    """The two call shapes of the one-chip LightGCN step: item rows
+    gathering from the user table, and user rows from the item table,
+    each traced once at the ring depth the shapes pick."""
+    depth = ring_plan(n_dst, D)[1]
+    before = obs.counters()["spmm_inflight"].get(depth, 0)
+    _compile(functools.partial(spmm_csr_pallas, "sum", n_nodes=n_dst,
+                               gather=True, interpret=False),
+             one_chip, ((n_src, D), jnp.float32), ((n_dst + 1,), jnp.int32),
+             ((E_CELL,), jnp.int32))
+    assert obs.counters()["spmm_inflight"][depth] == before + 1
 
 
 def test_hadamard_spmm_compiles_past_smem_limit(one_chip):
