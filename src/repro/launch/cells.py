@@ -276,14 +276,13 @@ def _gnn_full_ring(arch, mod, shape_name, shape, mesh):
     o_struct = jax.eval_shape(opt.init, p_struct)
     p_spec = shd.gcn_param_specs(cfg, mesh)
     o_spec = shd.opt_state_specs(mod.OPTIMIZER, p_spec, p_struct)
-    ring = make_ring_spmm(mesh, ax, n_local, with_coeff=True, n_steps=r,
-                          relative_buckets=True)
+    ring = make_ring_spmm(mesh, ax, n_local, with_coeff=True, n_steps=r)
 
-    def step(params, opt_state, x, src_l, dst_l, emask, coeff, labels, lmask):
+    def step(params, opt_state, x, src_l, indptr, coeff, labels, lmask):
         def loss_fn(p):
             h = x
             for li, w in enumerate(p["layers"]):
-                h = ring(h, src_l, dst_l, emask, coeff)
+                h = ring(h, src_l, indptr, coeff)
                 h = h @ w["w"] + w["b"]
                 if li + 1 < cfg.n_layers:
                     h = jax.nn.relu(h)
@@ -298,12 +297,12 @@ def _gnn_full_ring(arch, mod, shape_name, shape, mesh):
     bspec = NamedSharding(mesh, P(ax, None, None))
     args = (p_struct, o_struct,
             _sds((n_pad, shape["d_feat"]), F32),
-            _sds((nd, r, e_max), I32), _sds((nd, r, e_max), I32),
-            _sds((nd, r, e_max), jnp.bool_), _sds((nd, r, e_max), F32),
+            _sds((nd, r, e_max), I32), _sds((nd, r, n_local + 1), I32),
+            _sds((nd, r, e_max), F32),
             _sds((n_pad,), I32), _sds((n_pad,), F32))
     in_sh = (shd.named(mesh, p_spec), shd.named(mesh, o_spec),
              NamedSharding(mesh, P(ax, None)),
-             bspec, bspec, bspec, bspec,
+             bspec, bspec, bspec,
              NamedSharding(mesh, P(ax)), NamedSharding(mesh, P(ax)))
     out_sh = (shd.named(mesh, p_spec), shd.named(mesh, o_spec),
               NamedSharding(mesh, P()))

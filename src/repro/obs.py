@@ -34,8 +34,10 @@ compiles.  Trace-time counts join them through ``count``:
 ``spmm_inflight``, keyed by DMA ring depth, counts the traces of the
 Pallas SpMM (``kernels/spmm.py``), one per call shape a program lowers;
 ``ngcf_route``, keyed by the Hadamard route the graph resolved
-(``pipeline/sparse.py``), counts the traces of the NGCF forward.
-``counters()`` returns a snapshot.
+(``pipeline/sparse.py``), counts the traces of the NGCF forward;
+``ring_route``, keyed by the route a ring SpMM aggregates its buckets
+by (``pallas`` or ``xla``), counts the traces of the ring's per-device
+function (``dist/ring_spmm.py``).  ``counters()`` returns a snapshot.
 """
 from __future__ import annotations
 
@@ -59,7 +61,7 @@ _EVENTS = {
     "/jax/core/compile/backend_compile_duration": "compiles",
 }
 # counted by the program at trace time (``count``)
-_TRACE_COUNTS = ("spmm_inflight", "ngcf_route")
+_TRACE_COUNTS = ("spmm_inflight", "ngcf_route", "ring_route")
 
 
 def span(name: str, step: int):
@@ -118,6 +120,6 @@ def count(kind: str, key) -> None:
 
 def counters() -> dict:
     """``{"lowerings": {fun_name: n}, "compiles": {fun_name: n},
-    "spmm_inflight": {depth: n}, "ngcf_route": {route: n}}`` since this
-    module was first imported."""
+    "spmm_inflight": {depth: n}, "ngcf_route": {route: n},
+    "ring_route": {route: n}}`` since this module was first imported."""
     return _COUNTER.snapshot()

@@ -27,9 +27,12 @@ Sharded dispatch: alongside ``pallas``/``xla`` there is a ``ring``
 route (``ShardPlan.wants_ring``) that runs node aggregation through
 ``dist.ring_spmm`` over the *unified* node space (users then items,
 padded to a multiple of the shard count): features row-sharded over the
-device ring, edges bucketed by (dst device, ring distance), compute on
-bucket k overlapping the collective-permute fetching block k+1 — the
-paper's NUMA-blocked Fig 11 schedule as a device ring.  The symmetric
+device ring, edges bucketed by (dst device, ring distance) into CSR
+buckets, each ring step aggregating its bucket with the same
+gather-SpMM kernel as the CSR path (``impl``) and then permuting the
+block on — the paper's NUMA-blocked Fig 11 schedule as a device ring;
+the permute follows the bucket's compute, so little of it is hidden.
+The symmetric
 propagation becomes ONE ring SpMM per layer (both directions at once,
 since the unified adjacency is symmetric), and every ring op carries a
 custom VJP that is the transpose-direction ring — the same
@@ -171,7 +174,9 @@ class _RingGraph:
     ``sym`` applies the symmetric adjacency (both edge directions in
     one ring pass); ``ui``/``iu`` apply only the user->item /
     item->user direction; ``*_T`` are their exact transposes.  Each
-    op's bucket cubes (``arrays``, device arrays row-sharded over the
+    ring step aggregates through the graph's kernel dispatch ``impl``.
+    Each op's bucket cubes (``arrays``: source rows and row pointers,
+    device arrays row-sharded over the
     mesh) are built on first use, or up front by ``build`` — the
     pipeline builds the ops its model uses (``ModelSpec.ring_ops``)
     before the first trace, so the cubes reach the jitted step as
@@ -181,11 +186,12 @@ class _RingGraph:
     """
 
     def __init__(self, shard: ShardPlan, user: np.ndarray, item: np.ndarray,
-                 n_users: int, n_items: int):
+                 n_users: int, n_items: int, impl: str):
         from repro.dist.ring_spmm import bucket_edges, make_ring_spmm
         self._bucket_edges = bucket_edges
         self._make_ring_spmm = make_ring_spmm
         self.shard = shard
+        self.impl = impl
         self.n_users = int(n_users)
         self.n_items = int(n_items)
         self.part = shard.partition(n_users + n_items)
@@ -246,7 +252,7 @@ class _RingGraph:
                     f"ring op {key!r} has no bucket cubes in this graph "
                     f"view; build it before tracing (BipartiteCSR."
                     f"build_ring, ModelSpec.ring_ops)")
-            src_l, dst_l, mask, _ = self._bucket_edges(
+            src_l, indptr, _ = self._bucket_edges(
                 *self._edges(key), self.part.n_pad, self.shard.n_shards,
                 n_steps=self._steps(key))
             sh = jax.sharding.NamedSharding(
@@ -254,13 +260,14 @@ class _RingGraph:
                 jax.sharding.PartitionSpec(self.shard.dp, None, None))
             with jax.ensure_compile_time_eval():
                 self.arrays[key] = tuple(jax.device_put(a, sh)
-                                         for a in (src_l, dst_l, mask))
+                                         for a in (src_l, indptr))
 
     def _ring_fn(self, n_steps):
         if n_steps not in self._fns:
             self._fns[n_steps] = self._make_ring_spmm(
                 self.shard.build_mesh(), self.shard.dp, self.part.n_local,
-                n_steps=n_steps, quantize=self.shard.ring_quant)
+                n_steps=n_steps, quantize=self.shard.ring_quant,
+                impl=self.impl)
         return self._fns[n_steps]
 
     def matmul(self, which: str, x):
@@ -386,7 +393,8 @@ class BipartiteCSR:
 
         self._ring = None
         if self.spmm == "ring":
-            self._ring = _RingGraph(self.shard, user, item, n_users, n_items)
+            self._ring = _RingGraph(self.shard, user, item, n_users, n_items,
+                                    self.impl)
         if hadamard not in ("auto", "fused", "composed"):
             raise ValueError(f"hadamard must be 'auto', 'fused' or "
                              f"'composed', got {hadamard!r}")

@@ -442,12 +442,11 @@ def test_multidevice_quantized_ring_rotates_int8():
         src = rng.integers(0, n, e).astype(np.int32)
         dst = rng.integers(0, n, e).astype(np.int32)
         x = rng.standard_normal((n, d)).astype(np.float32)
-        src_l, dst_l, mask, per = bucket_edges(src, dst, n, n_dev)
+        src_l, indptr, per = bucket_edges(src, dst, n, n_dev)
         mesh = jax.make_mesh((n_dev,), ("data",))
         exact = make_ring_spmm(mesh, "data", per)
         quant = make_ring_spmm(mesh, "data", per, quantize=True)
-        args = (jnp.asarray(x), jnp.asarray(src_l), jnp.asarray(dst_l),
-                jnp.asarray(mask))
+        args = (jnp.asarray(x), jnp.asarray(src_l), jnp.asarray(indptr))
         with mesh:
             ref = np.asarray(jax.jit(exact)(*args))
             got = np.asarray(jax.jit(quant)(*args))

@@ -33,12 +33,12 @@ def test_ring_spmm_matches_dense():
         src = rng.integers(0, n, e).astype(np.int32)
         dst = rng.integers(0, n, e).astype(np.int32)
         x = rng.standard_normal((n, d)).astype(np.float32)
-        src_l, dst_l, mask, per = bucket_edges(src, dst, n, n_dev)
+        src_l, indptr, per = bucket_edges(src, dst, n, n_dev)
         mesh = jax.make_mesh((n_dev,), ("data",))
         fn = make_ring_spmm(mesh, "data", per)
         with mesh:
             out = jax.jit(fn)(jnp.asarray(x), jnp.asarray(src_l),
-                              jnp.asarray(dst_l), jnp.asarray(mask))
+                              jnp.asarray(indptr))
         a = np.zeros((n, n), np.float32)
         np.add.at(a, (dst, src), 1.0)
         np.testing.assert_allclose(np.asarray(out), a @ x, rtol=2e-4, atol=2e-4)
@@ -58,12 +58,12 @@ def test_ring_spmm_uses_collective_permute():
         src = rng.integers(0, n, e).astype(np.int32)
         dst = rng.integers(0, n, e).astype(np.int32)
         x = rng.standard_normal((n, d)).astype(np.float32)
-        src_l, dst_l, mask, per = bucket_edges(src, dst, n, n_dev)
+        src_l, indptr, per = bucket_edges(src, dst, n, n_dev)
         mesh = jax.make_mesh((n_dev,), ("data",))
         fn = make_ring_spmm(mesh, "data", per)
         with mesh:
             txt = jax.jit(fn).lower(jnp.asarray(x), jnp.asarray(src_l),
-                jnp.asarray(dst_l), jnp.asarray(mask)).compile().as_text()
+                jnp.asarray(indptr)).compile().as_text()
         from repro.analysis.hlo_audit import HloExpectation, assert_clean
         # the bare ring fn (unlike the full train step, where GSPMD
         # gathers embedding rows) must not all-gather the feature matrix
@@ -115,33 +115,54 @@ def test_production_mesh_shapes():
     assert "MESH_OK" in out
 
 
-def test_bucket_edges_vectorized_matches_loop():
-    """The single-lexsort bucketing pass must reproduce the retired
-    O(P·steps) selection loop's output layout exactly — same bucket
-    membership, same within-bucket order, same padding."""
+@pytest.mark.parametrize("n,p,e,steps,coeff", [
+    (64, 8, 500, None, True),
+    (64, 8, 500, 3, False),           # banded: drops edges
+    (48, 4, 1, None, True),
+    (16, 4, 0, 2, False),             # empty edge set
+    (96, 2, 300, 1, True),
+])
+def test_bucket_edges_emits_csr_buckets(n, p, e, steps, coeff):
+    """Each (dst device, ring step) bucket is a CSR over the device's
+    local rows: row pointers from 0 to the bucket's edge count, padding
+    past it, and exactly the edges a direct selection finds for the
+    bucket, ordered by local dst row and, within a row, as given."""
     import sys as _sys
     _sys.path.insert(0, "src")
-    from repro.dist.ring_spmm import _bucket_edges_loop, bucket_edges
+    from repro.dist.ring_spmm import bucket_edges
     rng = np.random.default_rng(42)
-    cases = [
-        dict(n=64, p=8, e=500, steps=None, coeff=True),
-        dict(n=64, p=8, e=500, steps=3, coeff=False),   # banded: drops edges
-        dict(n=48, p=4, e=1, steps=None, coeff=True),
-        dict(n=16, p=4, e=0, steps=2, coeff=False),     # empty edge set
-        dict(n=96, p=2, e=300, steps=1, coeff=True),
-    ]
-    for c in cases:
-        src = rng.integers(0, c["n"], c["e"]).astype(np.int32)
-        dst = rng.integers(0, c["n"], c["e"]).astype(np.int32)
-        coeff = rng.standard_normal(c["e"]).astype(np.float32) \
-            if c["coeff"] else None
-        new = bucket_edges(src, dst, c["n"], c["p"], coeff=coeff,
-                           n_steps=c["steps"])
-        old = _bucket_edges_loop(src, dst, c["n"], c["p"], coeff=coeff,
-                                 n_steps=c["steps"])
-        assert len(new) == len(old)
-        for a, b in zip(new, old):
-            np.testing.assert_array_equal(a, b)
+    src = rng.integers(0, n, e).astype(np.int32)
+    dst = rng.integers(0, n, e).astype(np.int32)
+    w = rng.standard_normal(e).astype(np.float32) if coeff else None
+    out = bucket_edges(src, dst, n, p, coeff=w, n_steps=steps)
+    src_l, indptr, coeff_l, n_local = out if coeff else (*out[:2], None,
+                                                         out[2])
+    s = p if steps is None else steps
+    assert n_local == n // p
+    assert src_l.shape[:2] == indptr.shape[:2] == (p, s)
+    assert indptr.shape[2] == n_local + 1
+    emax = src_l.shape[2]
+    assert emax % 8 == 0 and emax >= max(int(indptr[..., -1].max()), 1)
+    rel = (src // n_local - dst // n_local) % p
+    kept = 0
+    for d in range(p):
+        for k in range(s):
+            ptr = indptr[d, k]
+            assert ptr[0] == 0 and np.all(np.diff(ptr) >= 0)
+            cnt = int(ptr[-1])
+            sel = np.nonzero((dst // n_local == d) & (rel == k))[0]
+            sel = sel[np.argsort(dst[sel] % n_local, kind="stable")]
+            assert cnt == len(sel)
+            kept += cnt
+            rows = np.repeat(np.arange(n_local), np.diff(ptr))
+            np.testing.assert_array_equal(rows, dst[sel] % n_local)
+            np.testing.assert_array_equal(src_l[d, k, :cnt],
+                                          src[sel] % n_local)
+            assert not src_l[d, k, cnt:].any()
+            if coeff:
+                np.testing.assert_array_equal(coeff_l[d, k, :cnt], w[sel])
+                assert not coeff_l[d, k, cnt:].any()
+    assert kept == int(np.sum(rel < s))
 
 
 def test_bucket_edges_rejects_ragged_and_shard_layer_pads():
@@ -156,9 +177,9 @@ def test_bucket_edges_rejects_ragged_and_shard_layer_pads():
     part = ShardPlan(shape=(4,)).partition(10)
     assert part.n_pad == 12 and part.n_local == 3
     # padded rows exist but own no edges
-    src_l, dst_l, mask, n_local = bucket_edges(
+    src_l, indptr, n_local = bucket_edges(
         np.array([0, 9]), np.array([9, 0]), part.n_pad, 4)
-    assert n_local == 3 and int(mask.sum()) == 2
+    assert n_local == 3 and int(indptr[..., -1].sum()) == 2
 
 
 def test_ring_dispatch_matches_csr_forward_and_grads():
@@ -219,12 +240,11 @@ def test_banded_ring_matches_dense_on_band_complete_graph():
         sblk = (dst // per + off) % p
         src = (sblk * per + rng.integers(0, per, e)).astype(np.int32)
         x = rng.standard_normal((n, d)).astype(np.float32)
-        src_l, dst_l, mask, per_l = bucket_edges(src, dst, n, p,
-                                                 n_steps=steps)
+        src_l, indptr, per_l = bucket_edges(src, dst, n, p, n_steps=steps)
         mesh = jax.make_mesh((p,), ("data",))
         fn = make_ring_spmm(mesh, "data", per_l, n_steps=steps)
         out = jax.jit(fn)(jnp.asarray(x), jnp.asarray(src_l),
-                          jnp.asarray(dst_l), jnp.asarray(mask))
+                          jnp.asarray(indptr))
         a = np.zeros((n, n), np.float32)
         np.add.at(a, (dst, src), 1.0)
         np.testing.assert_allclose(np.asarray(out), a @ x,
@@ -282,6 +302,86 @@ def test_banded_ring_gradients_match_dense_banded_operator():
         print("BANDED_GRAD_OK")
     """, n=4)
     assert "BANDED_GRAD_OK" in out
+
+
+@pytest.mark.parametrize("which,steps", [("sym", None), ("ui", None),
+                                         ("sym", 2)])
+def test_ring_pallas_route_matches_dense(which, steps):
+    """Each ring bucket aggregated by the Pallas gather-SpMM (interpret
+    mode here) inside the ring's shard_map: ``_RingGraph.matmul``'s
+    forward equals the dense ``A @ x`` and its VJP ``A^T @ ct``, on a
+    ragged graph (padded rows), for the symmetric op, one direction,
+    and a banded ring that drops edges; the route is counted."""
+    out = run_with_devices(f"""
+        import jax, jax.numpy as jnp, numpy as np
+        from repro import obs
+        from repro.data import synth
+        from repro.pipeline.shard import ShardPlan
+        from repro.pipeline.sparse import BipartiteCSR
+        p, steps, which = 4, {steps}, "{which}"
+        nu, ni = 27, 18                       # N=45, padded to 48
+        data = synth.generate_bipartite(nu, ni, 200, seed=11)
+        g = BipartiteCSR(data.user, data.item, nu, ni, impl="pallas",
+                         shard=ShardPlan(shape=(p,), ring_steps=steps))
+        ring = g._ring
+        n, n_local = ring.part.n_pad, ring.part.n_local
+        u, i = data.user, data.item + nu
+        s_all, d_all = {{"sym": (np.concatenate([u, i]),
+                                 np.concatenate([i, u])),
+                        "ui": (u, i)}}[which]
+        rel = (s_all // n_local - d_all // n_local) % p
+        keep = rel < (steps or p)
+        if steps:
+            assert 0 < keep.sum() < len(keep)
+        a = np.zeros((n, n), np.float32)
+        np.add.at(a, (d_all[keep], s_all[keep]), 1.0)
+        rng = np.random.default_rng(2)
+        x = jnp.asarray(rng.standard_normal((n, 8)).astype(np.float32))
+        ct = jnp.asarray(rng.standard_normal((n, 8)).astype(np.float32))
+        before = obs.counters()["ring_route"].get("pallas", 0)
+        y, vjp = jax.vjp(lambda v: ring.matmul(which, v), x)
+        np.testing.assert_allclose(y, a @ np.asarray(x), rtol=2e-5,
+                                   atol=2e-5)
+        np.testing.assert_allclose(vjp(ct)[0], a.T @ np.asarray(ct),
+                                   rtol=2e-5, atol=2e-5)
+        assert obs.counters()["ring_route"]["pallas"] > before
+        assert "xla" not in obs.counters()["ring_route"]
+        print("RING_PALLAS_OK")
+    """, n=4)
+    assert "RING_PALLAS_OK" in out
+
+
+def test_weighted_ring_keeps_the_xla_route():
+    """A ring whose edges carry coefficients has no Pallas operand for
+    them, so it aggregates on the XLA route whatever ``impl`` asks, and
+    counts that route; banded, it still equals the dense weighted
+    product over the kept edges."""
+    out = run_with_devices("""
+        import jax, jax.numpy as jnp, numpy as np
+        from repro import obs
+        from repro.dist.ring_spmm import bucket_edges, make_ring_spmm
+        p, n, d, e, steps = 4, 32, 8, 300, 3
+        rng = np.random.default_rng(4)
+        src = rng.integers(0, n, e).astype(np.int32)
+        dst = rng.integers(0, n, e).astype(np.int32)
+        w = rng.standard_normal(e).astype(np.float32)
+        x = rng.standard_normal((n, d)).astype(np.float32)
+        src_l, indptr, coeff_l, per = bucket_edges(src, dst, n, p, coeff=w,
+                                                   n_steps=steps)
+        mesh = jax.make_mesh((p,), ("data",))
+        fn = make_ring_spmm(mesh, "data", per, with_coeff=True,
+                            n_steps=steps, impl="pallas")
+        out = jax.jit(fn)(jnp.asarray(x), jnp.asarray(src_l),
+                          jnp.asarray(indptr), jnp.asarray(coeff_l))
+        keep = (src // per - dst // per) % p < steps
+        a = np.zeros((n, n), np.float32)
+        np.add.at(a, (dst[keep], src[keep]), w[keep])
+        np.testing.assert_allclose(np.asarray(out), a @ x, rtol=2e-4,
+                                   atol=2e-4)
+        assert obs.counters()["ring_route"] == {"xla": 1}
+        print("WEIGHTED_RING_OK")
+    """, n=4)
+    assert "WEIGHTED_RING_OK" in out
 
 
 def test_sharded_fit_matches_single_device_trajectory():

@@ -15,8 +15,10 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
 
 from repro import obs
 from repro.kernels.ann import ann_block_scores_pallas
@@ -30,19 +32,24 @@ E_CELL = 4_194_228     # lightgcn-m25-train's graph: 2^22 edges, deduplicated
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        desc = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 — no TPU compiler installed
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     # compiles for a described chip cannot be read back from the cache
     enabled = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
-    yield SingleDeviceSharding(topo.devices[0])
+    yield desc
     jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _compile(fn, sharding, *shapes):
@@ -76,6 +83,31 @@ def test_spmm_ring_compiles_at_the_training_cells_shapes(one_chip, n_dst,
              one_chip, ((n_src, D), jnp.float32), ((n_dst + 1,), jnp.int32),
              ((E_CELL,), jnp.int32))
     assert obs.counters()["spmm_inflight"][depth] == before + 1
+
+
+def test_ring_spmm_compiles_pallas_under_shard_map(topo, monkeypatch):
+    """The ring cell's aggregation on the four chips of the described
+    host: each bucket through the Pallas SpMM inside the ring's
+    shard_map, the blocks rotating by collective-permute.  Shapes: the
+    cell's padded node count over four chips and a bucket about as long
+    as its largest."""
+    from repro.dist.ring_spmm import make_ring_spmm
+    from repro.kernels import ops as kops
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    n_pad, bucket = 402_432, 2_200_000
+    n_local = n_pad // 4
+    monkeypatch.setattr(kops, "_on_tpu", lambda: True)
+    ring = make_ring_spmm(mesh, "data", n_local, impl="pallas")
+    rows = NamedSharding(mesh, P("data", None))
+    cube = NamedSharding(mesh, P("data", None, None))
+    before = obs.counters()["ring_route"].get("pallas", 0)
+    text = jax.jit(ring).lower(
+        jax.ShapeDtypeStruct((n_pad, D), jnp.float32, sharding=rows),
+        jax.ShapeDtypeStruct((4, 4, bucket), jnp.int32, sharding=cube),
+        jax.ShapeDtypeStruct((4, 4, n_local + 1), jnp.int32, sharding=cube),
+    ).compile().as_text()
+    assert "tpu_custom_call" in text and "collective-permute" in text
+    assert obs.counters()["ring_route"]["pallas"] == before + 1
 
 
 def test_hadamard_spmm_compiles_past_smem_limit(one_chip):
