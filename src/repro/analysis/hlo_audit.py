@@ -82,9 +82,6 @@ FRAGMENTS = {
     # that the permute is present)
     "ring-spmm": HloExpectation("ring-spmm",
                                 contains=("collective-permute",)),
-    # quantized ring: the rotated payload really is s8 (1/4 wire bytes)
-    "ring-spmm@int8": HloExpectation("ring-spmm@int8",
-                                     contains=("collective-permute", "s8")),
     # sharded training psums grads with a plain all-reduce
     "grad-psum": HloExpectation("grad-psum", contains=("all-reduce",)),
     # int8 gradient combine: a REAL integer all-reduce (int8 payload,
@@ -127,13 +124,13 @@ def expect(*names: str) -> HloExpectation:
     return exp
 
 
-def expectation_for(*, n_shards: int = 1, grads: str = "none",
-                    ring: str = "none") -> HloExpectation:
+def expectation_for(*, n_shards: int = 1,
+                    grads: str = "none") -> HloExpectation:
     """The full train-step expectation for a (MeshCfg, CompressionCfg)
     point: which fragments apply is a pure function of the config."""
     if n_shards <= 1:
         return expect("single-device")
-    names = ["ring-spmm@int8" if ring == "int8" else "ring-spmm"]
+    names = ["ring-spmm"]
     if grads == "topk":
         names.append("grad-combine@topk")
     elif grads == "int8":
@@ -259,9 +256,7 @@ def audit_spec(spec, *, serve: bool = True, n_epochs: int = 8
     n_shards = 1
     for d in spec.mesh.shape:
         n_shards *= int(d)
-    exp = expectation_for(n_shards=n_shards,
-                          grads=spec.compression.grads,
-                          ring=spec.compression.ring)
+    exp = expectation_for(n_shards=n_shards, grads=spec.compression.grads)
     violations = []
     for name, txt in lower_train_step(run).items():
         # the collective contract binds the aggregation step; the
@@ -328,7 +323,7 @@ _SMOKE_OV = {"loop.steps": 5, "plan.target_batch": 64,
              "data.edges": 1200, "loop.ckpt_dir": None}
 
 
-def smoke_audit(mesh: int = 1, grads: str = "none", ring: str = "none",
+def smoke_audit(mesh: int = 1, grads: str = "none",
                 embed_store: str = "fp32", fused_serve: bool = True,
                 arch: str = "lightgcn") -> list[str]:
     """The representative-preset audit ``make audit`` runs: the
@@ -343,11 +338,10 @@ def smoke_audit(mesh: int = 1, grads: str = "none", ring: str = "none",
     ov = dict(_SMOKE_OV)
     if mesh > 1:
         ov.update({"mesh.shape": (mesh,), "plan.microbatch": 4})
-    ov.update({"compression.grads": grads, "compression.ring": ring,
+    ov.update({"compression.grads": grads,
                "compression.embed_store": embed_store})
     spec = get_preset(f"{arch}-smoke").override(ov)
-    name = f"{arch}-smoke[mesh={mesh},grads={grads},ring={ring}" \
-           f",store={embed_store}]"
+    name = f"{arch}-smoke[mesh={mesh},grads={grads},store={embed_store}]"
     spec = spec.override({"name": name})
     violations = audit_spec(spec, serve=fused_serve)
     if arch == "ngcf":

@@ -62,19 +62,18 @@ class PlanCfg:
     base_batch: int = 256            # LR-scaling reference batch
     warmup_epochs: int = 2           # warm-up batch = target/10 epochs
     lr_scaling: str = "linear"       # 'linear' | 'sqrt'
-    impl: str | None = None          # kernel dispatch override
+    impl: str | None = None          # None (backend) | 'pallas' | 'xla'
 
 
 @dataclasses.dataclass(frozen=True)
 class MeshCfg:
     """Sharded execution (``pipeline.shard.ShardPlan``): mesh shape and
-    axis names, SpMM dispatch, and the banded-ring knob.  The default
+    axis names, and the SpMM dispatch.  The default
     ``shape=(1,)`` is the inert single-device plan — bit-identical to
     the unsharded pipeline (pinned by tests/test_api.py)."""
     shape: tuple[int, ...] = (1,)
     axes: tuple[str, ...] | None = None  # None -> auto axis names
     spmm: str | None = None          # None (auto: ring when P>1) | 'ring'
-    ring_steps: int | None = None    # banded ring: visit n_steps < P owners
 
     def __post_init__(self):
         object.__setattr__(self, "shape",
@@ -82,9 +81,6 @@ class MeshCfg:
         if self.axes is not None:
             object.__setattr__(self, "axes",
                                tuple(str(a) for a in self.axes))
-        if self.ring_steps is not None and self.ring_steps < 1:
-            raise ValueError(f"mesh.ring_steps must be >= 1 (or null for "
-                             f"the full ring), got {self.ring_steps}")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -119,15 +115,13 @@ class CompressionCfg:
     all-gather, with per-participant error feedback carried in
     ``state["comp"]``), capacity-tier embedding-table storage
     (``embed_store='int8'``: ~1/4 bytes, fp32 dequant-on-gather, and
-    the planner prices the quantized footprint), and the ring-SpMM
-    payload rotation (``ring='int8'``).  The default is the identity:
-    no compressor is built and training stays bit-identical to the
-    pre-compression pipeline (pinned by tests/test_compression.py)."""
+    the planner prices the quantized footprint).  The default is the
+    identity: no compressor is built and training stays bit-identical
+    to the pre-compression pipeline (pinned by tests/test_compression.py)."""
     grads: str = "none"              # 'none' | 'int8' | 'topk'
     frac: float = 0.01               # top-k kept fraction of each tensor
     error_feedback: bool = True      # carry compression residuals
     embed_store: str = "fp32"        # 'fp32' | 'int8' slow-tier tables
-    ring: str = "none"               # 'none' | 'int8' ring payload
 
     def __post_init__(self):
         if self.grads not in ("none", "int8", "topk"):
@@ -139,9 +133,6 @@ class CompressionCfg:
         if self.embed_store not in ("fp32", "int8"):
             raise ValueError(f"compression.embed_store must be 'fp32' or "
                              f"'int8', got {self.embed_store!r}")
-        if self.ring not in ("none", "int8"):
-            raise ValueError(f"compression.ring must be 'none' or 'int8', "
-                             f"got {self.ring!r}")
         object.__setattr__(self, "frac", float(self.frac))
         object.__setattr__(self, "error_feedback", bool(self.error_feedback))
 
@@ -284,7 +275,6 @@ class ExperimentSpec:
             hbm_budget=self.plan.hbm_budget, impl=self.plan.impl,
             seed=self.seed, mesh_shape=self.mesh.shape,
             mesh_axes=self.mesh.axes, spmm=self.mesh.spmm,
-            ring_steps=self.mesh.ring_steps,
             memory_topology=self.memory.topology,
             memory_policy=self.memory.policy,
             memory_capacity=self.memory.capacity,
@@ -293,7 +283,6 @@ class ExperimentSpec:
             compression_frac=self.compression.frac,
             compression_ef=self.compression.error_feedback,
             embed_store=self.compression.embed_store,
-            ring_compression=self.compression.ring,
             eval_k=self.eval.k,
             eval_user_batch=self.eval.user_batch,
             eval_item_block=self.eval.item_block)

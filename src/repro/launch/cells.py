@@ -206,12 +206,6 @@ def _gcn_param_struct(cfg):
 
 
 def _gnn_full(arch, mod, shape_name, shape, mesh):
-    import os
-    # REPRO_GNN_IMPL=ring selects the ring-SpMM variant (the §Perf
-    # hillclimb); REPRO_RING_STEPS=R bounds the ring radius (locality-
-    # partitioned graph, paper §6 blocked placement + §8.1 reordering)
-    if os.environ.get("REPRO_GNN_IMPL") == "ring":
-        return _gnn_full_ring(arch, mod, shape_name, shape, mesh)
     from repro.core.graph import Graph
     from repro.models import gcn
     cfg = _gcn_cfg_for_shape(mod, shape)
@@ -252,76 +246,6 @@ def _gnn_full(arch, mod, shape_name, shape, mesh):
                 donate=(0, 1),
                 meta=dict(model_flops=3 * (spmm_flops + mm_flops),
                           n_nodes=shape["n_nodes"], n_edges=shape["n_edges"]))
-
-
-def _gnn_full_ring(arch, mod, shape_name, shape, mesh):
-    """Ring-SpMM variant of full-graph GCN training: node-sharded
-    features rotate around the flattened device ring (overlapped
-    ppermute) instead of GSPMD gather/all-reduce.  Edge buckets are
-    relative-banded: REPRO_RING_STEPS (default: full ring) owners per
-    device, from locality-aware partitioning."""
-    import os
-    from repro.dist.ring_spmm import make_ring_spmm
-    from repro.models import gcn
-    cfg = _gcn_cfg_for_shape(mod, shape)
-    opt = _make_opt(mod.OPTIMIZER)
-    nd = mesh.devices.size
-    ax = all_axes(mesh)
-    n_pad = _round_up(shape["n_nodes"], nd)
-    n_local = n_pad // nd
-    r = int(os.environ.get("REPRO_RING_STEPS", nd))
-    e_max = _round_up(int(shape["n_edges"] / (nd * r) * 1.3) + 8, 8)
-
-    p_struct = _gcn_param_struct(cfg)
-    o_struct = jax.eval_shape(opt.init, p_struct)
-    p_spec = shd.gcn_param_specs(cfg, mesh)
-    o_spec = shd.opt_state_specs(mod.OPTIMIZER, p_spec, p_struct)
-    ring = make_ring_spmm(mesh, ax, n_local, with_coeff=True, n_steps=r)
-
-    def step(params, opt_state, x, src_l, indptr, coeff, labels, lmask):
-        def loss_fn(p):
-            h = x
-            for li, w in enumerate(p["layers"]):
-                h = ring(h, src_l, indptr, coeff)
-                h = h @ w["w"] + w["b"]
-                if li + 1 < cfg.n_layers:
-                    h = jax.nn.relu(h)
-            logp = jax.nn.log_softmax(h, -1)
-            ll = jnp.take_along_axis(logp, labels[:, None], -1)[:, 0]
-            return -jnp.sum(ll * lmask) / jnp.maximum(lmask.sum(), 1.0)
-
-        loss, grads = jax.value_and_grad(loss_fn)(params)
-        params, opt_state = opt.update(grads, opt_state, params)
-        return params, opt_state, loss
-
-    bspec = NamedSharding(mesh, P(ax, None, None))
-    args = (p_struct, o_struct,
-            _sds((n_pad, shape["d_feat"]), F32),
-            _sds((nd, r, e_max), I32), _sds((nd, r, n_local + 1), I32),
-            _sds((nd, r, e_max), F32),
-            _sds((n_pad,), I32), _sds((n_pad,), F32))
-    in_sh = (shd.named(mesh, p_spec), shd.named(mesh, o_spec),
-             NamedSharding(mesh, P(ax, None)),
-             bspec, bspec, bspec,
-             NamedSharding(mesh, P(ax)), NamedSharding(mesh, P(ax)))
-    out_sh = (shd.named(mesh, p_spec), shd.named(mesh, o_spec),
-              NamedSharding(mesh, P()))
-    dims = [shape["d_feat"]] + [cfg.d_hidden] * (cfg.n_layers - 1) + [cfg.n_classes]
-    spmm_flops = sum(2 * shape["n_edges"] * d for d in dims[:-1])
-    mm_flops = sum(2 * shape["n_nodes"] * dims[i] * dims[i + 1]
-                   for i in range(len(dims) - 1))
-    # ring collective bytes (analytic; ppermute sits in a fori_loop so the
-    # HLO text counts it once): fwd+bwd per layer, R steps moving the
-    # whole feature matrix once per full rotation fraction
-    ring_bytes = 2 * r / nd * sum(n_pad * d * 4 for d in dims[:-1])
-    hbm_bytes = 3 * sum(2 * shape["n_edges"] * d * 4 + 3 * n_pad * d * 4
-                        for d in dims[:-1])
-    return Cell(arch, shape_name, "gnn_full", step, args, in_sh, out_sh,
-                donate=(0, 1),
-                meta=dict(model_flops=3 * (spmm_flops + mm_flops),
-                          n_nodes=shape["n_nodes"], n_edges=shape["n_edges"],
-                          ring_steps=r, ring_coll_bytes=ring_bytes,
-                          ring_hbm_bytes=hbm_bytes))
 
 
 def _gnn_sampled(arch, mod, shape_name, shape, mesh):

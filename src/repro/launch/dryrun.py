@@ -41,13 +41,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, verbose: bool = True):
     compile_s = time.perf_counter() - t0
     mem = compiled.memory_analysis()
     analytic_cost = None
-    if "ring_coll_bytes" in cell.meta:
-        # ring variant: the ppermute/segment_sum sit inside fori_loop
-        # bodies (counted once by HloCostAnalysis) -> analytic terms
-        analytic_cost = dict(flops=cell.meta["model_flops"],
-                             hbm_bytes=cell.meta["ring_hbm_bytes"],
-                             coll_bytes=cell.meta["ring_coll_bytes"])
-    elif "analytic_hbm" in cell.meta:
+    if "analytic_hbm" in cell.meta:
         # recsys trains: XLA 'bytes accessed' badly under-counts dense
         # optimizer table streaming; use the documented analytic model
         analytic_cost = dict(flops=cell.meta["model_flops"],

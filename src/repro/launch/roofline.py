@@ -10,7 +10,8 @@ for every all-gather / all-reduce / reduce-scatter / all-to-all /
 collective-permute instruction we take the largest shape named in the
 instruction (the full buffer that crosses links) — an upper-bound proxy;
 loop-carried collectives count once per appearance (documented
-limitation; ring schedules multiply analytically in benchmarks).
+limitation: the ring SpMM's permute sits in a fori_loop body, so its P
+steps count as one).
 """
 from __future__ import annotations
 

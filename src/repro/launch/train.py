@@ -20,7 +20,7 @@ eval all ride along.
 Sharded execution (mesh-parallel full-graph training; CPU CI uses
 XLA_FLAGS=--xla_force_host_platform_device_count=4):
 
-  python -m repro.launch.train --arch lightgcn --mesh 4 --ring-steps 2
+  python -m repro.launch.train --arch lightgcn --mesh 4
 """
 from __future__ import annotations
 
@@ -87,9 +87,6 @@ def build_arg_parser() -> argparse.ArgumentParser:
                     "e.g. '4' or '2x2' (spec override mesh.shape); on CPU "
                     "pair with XLA_FLAGS=--xla_force_host_platform_"
                     "device_count=N")
-    ap.add_argument("--ring-steps", type=int,
-                    help="banded ring: visit only this many source owners "
-                         "per SpMM (mesh.ring_steps; 0 = full ring)")
     ap.add_argument("--spmm", choices=["auto", "ring"],
                     help="aggregation dispatch (mesh.spmm); 'ring' forces "
                          "the ring route even on one device")
@@ -117,8 +114,6 @@ def build_arg_parser() -> argparse.ArgumentParser:
                     help="capacity-tier embedding-table storage "
                          "(compression.embed_store): int8 = ~1/4 bytes, "
                          "fp32 dequant-on-gather")
-    ap.add_argument("--compress-ring", choices=["none", "int8"],
-                    help="ring-SpMM payload rotation (compression.ring)")
     return ap
 
 
@@ -166,8 +161,6 @@ def spec_from_args(args: argparse.Namespace) -> ExperimentSpec:
     if args.mesh is not None:
         from repro.pipeline.shard import parse_mesh
         ov["mesh.shape"] = parse_mesh(args.mesh)
-    if args.ring_steps is not None:
-        ov["mesh.ring_steps"] = args.ring_steps or None
     if args.spmm is not None:
         ov["mesh.spmm"] = None if args.spmm == "auto" else args.spmm
     if args.memory_topology is not None:
@@ -180,8 +173,6 @@ def spec_from_args(args: argparse.Namespace) -> ExperimentSpec:
         ov["compression.frac"] = args.compress_frac
     if args.embed_store is not None:
         ov["compression.embed_store"] = args.embed_store
-    if args.compress_ring is not None:
-        ov["compression.ring"] = args.compress_ring
     if args.pin:
         pins = dict(spec.memory.pins or {})
         for entry in args.pin:

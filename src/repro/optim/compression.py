@@ -17,9 +17,7 @@ engine threads through ``PipelineConfig``):
       ``ErrorFeedback`` residuals carried in the training state);
   ``memory.executor.TieredExecutor``    — int8 storage for
       capacity-tier embedding tables (``compression.embed_store``),
-      fp32 dequant-on-gather via ``quantize_rows_int8``;
-  ``dist.ring_spmm``                    — int8 ring payload rotation
-      (``compression.ring``).
+      fp32 dequant-on-gather via ``quantize_rows_int8``.
 
 The roofline/fig7 collective and capacity-tier byte terms scale down by
 the active scheme (``benchmarks`` emits the before/after as

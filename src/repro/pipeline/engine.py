@@ -66,8 +66,8 @@ class PipelineConfig:
     lr_scaling: str = "linear"         # 'linear' | 'sqrt' (paper ablation)
     l2: float = 1e-4
     hbm_budget: int | None = None      # fast-tier budget override (bytes/device)
-    impl: str | None = None            # kernel dispatch override; 'ring'
-    #                                    forces the sharded aggregation route
+    impl: str | None = None            # kernel dispatch override:
+    #                                    None (backend) | 'pallas' | 'xla'
     hadamard: str = "auto"             # NGCF Hadamard route: 'auto' |
     #                                    'fused' (no [E, D]) | 'composed'
     seed: int = 0
@@ -84,16 +84,14 @@ class PipelineConfig:
     mesh_shape: tuple[int, ...] = (1,)
     mesh_axes: tuple[str, ...] | None = None   # None -> auto axis names
     spmm: str | None = None            # None (auto) | 'ring'
-    ring_steps: int | None = None      # banded ring band (n_steps < P)
     # byte compression on the slow links (repro.api.CompressionCfg ->
-    # optim.compression): the gradient combine, capacity-tier embedding
-    # storage, and the ring payload.  The 'none'/'fp32' defaults build
-    # no compressor and stay bit-identical to the exact pipeline.
+    # optim.compression): the gradient combine and capacity-tier
+    # embedding storage.  The 'none'/'fp32' defaults build no
+    # compressor and stay bit-identical to the exact pipeline.
     grad_compression: str = "none"     # 'none' | 'int8' | 'topk'
     compression_frac: float = 0.01     # top-k kept fraction
     compression_ef: bool = True        # carry compression residuals
     embed_store: str = "fp32"          # 'fp32' | 'int8' slow-tier tables
-    ring_compression: str = "none"     # 'none' | 'int8' ring payload
     # held-out streaming evaluation (repro.eval); cadence lives in the
     # loop's LoopConfig.eval_every — these shape one eval sweep
     eval_k: int = 20
@@ -110,18 +108,16 @@ class Pipeline:
         self.spec = get_model(cfg.arch)
         # one ShardPlan flows through every layer below; None = the
         # inert single-device path, bit-identical to the pre-shard
-        # pipeline.  impl='ring' forces the ring route (BipartiteCSR
-        # builds a degenerate 1-device plan when no mesh is configured).
-        self.shard = ShardPlan.from_config(
-            cfg.mesh_shape, cfg.mesh_axes, cfg.spmm, cfg.ring_steps,
-            ring_quant=(cfg.ring_compression == "int8"))
+        # pipeline.  spmm='ring' forces the ring route (a degenerate
+        # 1-device ring when no mesh is configured).
+        self.shard = ShardPlan.from_config(cfg.mesh_shape, cfg.mesh_axes,
+                                           cfg.spmm)
         self.g = BipartiteCSR(train.user, train.item, train.n_users,
                               train.n_items, impl=cfg.impl, shard=self.shard,
                               hadamard=cfg.hadamard)
-        self.shard = self.g.shard
         # ring cubes exist before the planner sizes them and before the
         # first trace, so the jitted step takes them as arguments
-        self.g.build_ring(self.spec.ring_ops)
+        self.g.build_ring(("sym",))
         impl = self.g.impl                     # kernel impl: pallas | xla
         self.n_items = train.n_items
 

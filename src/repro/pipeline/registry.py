@@ -43,8 +43,6 @@ class ModelSpec:
     forward: Callable       # (params, g, n_layers) -> (user_emb, item_emb)
     materializes_messages: bool   # has a route with an [E, D] edge matrix
     concat_layers: bool = False   # output concatenates all layer embeddings
-    # ring SpMMs the forward and its VJP apply under the ring dispatch
-    ring_ops: tuple[str, ...] = ("sym", "sym_T")
 
     def out_dim(self, embed_dim: int, n_layers: int) -> int:
         """Final embedding width (drives the planner's per-sample cost)."""

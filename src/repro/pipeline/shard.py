@@ -9,7 +9,8 @@ through every layer:
   * ``pipeline.sparse``   — routes model aggregation through the ring
     SpMM (``dist.ring_spmm``) when ``wants_ring``: features row-sharded
     over the device ring, edges bucketed by (dst device, ring distance),
-    the NUMA-blocked Fig 11 schedule as collective-permutes;
+    the NUMA-blocked Fig 11 schedule as collective-permutes — always the
+    full ring of P steps, so every edge is applied exactly;
   * ``pipeline.plan``     — profiles *per-device* tensor shards and runs
     the tiered-memory knapsack against the per-device HBM budget; the
     derived microbatch is the per-shard microbatch (global batch =
@@ -107,7 +108,7 @@ class NodePartition:
 
 @dataclasses.dataclass(frozen=True)
 class ShardPlan:
-    """Mesh shape/axes + SpMM dispatch + ring band for one run.
+    """Mesh shape/axes + SpMM dispatch for one run.
 
     ``spmm``: ``None`` = auto (ring when the mesh has >1 device, the
     plain kernel dispatch otherwise); ``"ring"`` forces the ring path
@@ -117,14 +118,10 @@ class ShardPlan:
     shape: tuple[int, ...] = (1,)
     axes: tuple[str, ...] = ("data",)
     spmm: str | None = None          # None (auto) | 'ring'
-    ring_steps: int | None = None    # banded ring: visit only n_steps owners
-    ring_quant: bool = False         # int8 ring payload rotation
-    #                                  (CompressionCfg.ring = 'int8')
 
     def __post_init__(self):
         object.__setattr__(self, "shape", tuple(int(s) for s in self.shape))
         object.__setattr__(self, "axes", tuple(str(a) for a in self.axes))
-        object.__setattr__(self, "ring_quant", bool(self.ring_quant))
         if len(self.shape) != len(self.axes):
             raise ValueError(f"mesh shape {self.shape} has "
                              f"{len(self.shape)} dims but axes {self.axes} "
@@ -132,9 +129,6 @@ class ShardPlan:
         if self.spmm not in (None, "ring"):
             raise ValueError(f"unknown spmm dispatch {self.spmm!r}; "
                              "known: None (auto), 'ring'")
-        if self.ring_steps is not None and self.ring_steps < 1:
-            raise ValueError(f"ring_steps must be >= 1 (or None for the "
-                             f"full ring), got {self.ring_steps}")
 
     # ------------------------------------------------------------ shape
     @property
@@ -212,22 +206,20 @@ class ShardPlan:
         return 1
 
     def describe(self) -> str:
-        band = f" ring_steps={self.ring_steps}" if self.ring_steps else ""
-        quant = " ring_quant=int8" if self.ring_quant else ""
         return (f"mesh={'x'.join(map(str, self.shape))} "
                 f"axes={','.join(self.axes)} "
-                f"spmm={'ring' if self.wants_ring else 'kernel'}{band}{quant}")
+                f"spmm={'ring' if self.wants_ring else 'kernel'}")
 
     # ------------------------------------------------------------ builders
     @classmethod
-    def from_config(cls, mesh_shape=(1,), mesh_axes=None, spmm=None,
-                    ring_steps=None, ring_quant=False) -> "ShardPlan | None":
+    def from_config(cls, mesh_shape=(1,), mesh_axes=None,
+                    spmm=None) -> "ShardPlan | None":
         """The engine-facing constructor: returns ``None`` for the inert
         single-device default (no mesh, bit-identical legacy path), a
         live plan otherwise."""
         shape = tuple(int(s) for s in mesh_shape)
         axes = tuple(mesh_axes) if mesh_axes else auto_axes(shape)
-        plan = cls(shape, axes, spmm, ring_steps, ring_quant)
+        plan = cls(shape, axes, spmm)
         if not plan.is_sharded and not plan.wants_ring:
             return None
         return plan

@@ -23,19 +23,20 @@ for NGCF, whose Hadamard term factors into node rows times the same
 propagation (only its 'composed' route forms one edge matrix per
 layer, and the planner's tensor set reflects that).
 
-Sharded dispatch: alongside ``pallas``/``xla`` there is a ``ring``
-route (``ShardPlan.wants_ring``) that runs node aggregation through
-``dist.ring_spmm`` over the *unified* node space (users then items,
-padded to a multiple of the shard count): features row-sharded over the
-device ring, edges bucketed by (dst device, ring distance) into CSR
-buckets, each ring step aggregating its bucket with the same
-gather-SpMM kernel as the CSR path (``impl``) and then permuting the
-block on — the paper's NUMA-blocked Fig 11 schedule as a device ring;
-the permute follows the bucket's compute, so little of it is hidden.
-The symmetric
-propagation becomes ONE ring SpMM per layer (both directions at once,
-since the unified adjacency is symmetric), and every ring op carries a
-custom VJP that is the transpose-direction ring — the same
+Sharded dispatch: a ``ShardPlan`` that wants the ring
+(``ShardPlan.wants_ring``: a mesh of P > 1, or ``spmm='ring'``) runs
+node aggregation through ``dist.ring_spmm`` over the *unified* node
+space (users then items, padded to a multiple of the shard count):
+features row-sharded over the device ring, edges bucketed by (dst
+device, ring distance) into CSR buckets, each ring step aggregating its
+bucket with the same gather-SpMM kernel as the CSR path (``impl``) and
+then permuting the block on — the paper's NUMA-blocked Fig 11 schedule
+as a device ring; the permute follows the bucket's compute, so little
+of it is hidden.  The ring has one schedule: all P steps, an fp32
+block, unweighted buckets.  The symmetric propagation becomes ONE ring
+SpMM per layer (both directions at once, since the unified adjacency is
+symmetric), and every ring op carries a custom VJP that is the
+transpose ring (``sym`` itself, ``ui``/``iu`` each other) — the same
 gradients-map-onto-the-same-kernels structure (§4) as the CSR path.
 
 ``BipartiteCSR`` is a pytree: its index arrays (and the ring bucket
@@ -52,6 +53,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
+from repro.dist.ring_spmm import bucket_edges, make_ring_spmm
 from repro.kernels import ops as kops
 from repro.kernels.spmm import build_csr_by_dst
 from repro.pipeline.shard import ShardPlan
@@ -146,22 +148,27 @@ def _hadamard_agg_bwd(impl, n_dst, n_src, res, ct):
 _hadamard_agg.defvjp(_hadamard_agg_fwd, _hadamard_agg_bwd)
 
 
-def _ring_matmul(fwd_fn, bwd_fn):
+def _ring_matmul(ring_fn):
     """x_pad -> A x_pad through one ring closure, with the VJP riding the
     transpose ring.  The bucket arrays are arguments (no cotangent)."""
 
     @jax.custom_vjp
     def matmul(x, fwd, bwd):
-        return fwd_fn(x, *fwd)
+        return ring_fn(x, *fwd)
 
     def fwd_rule(x, fwd, bwd):
-        return fwd_fn(x, *fwd), bwd
+        return ring_fn(x, *fwd), bwd
 
     def bwd_rule(bwd, ct):
-        return bwd_fn(ct, *bwd), None, None
+        return ring_fn(ct, *bwd), None, None
 
     matmul.defvjp(fwd_rule, bwd_rule)
     return matmul
+
+
+# transposes of the ring ops: sym is self-adjoint, ui/iu are mutual
+# transposes
+_RING_T = {"sym": "sym", "ui": "iu", "iu": "ui"}
 
 
 # ---------------------------------------------------------------- ring
@@ -173,88 +180,51 @@ class _RingGraph:
 
     ``sym`` applies the symmetric adjacency (both edge directions in
     one ring pass); ``ui``/``iu`` apply only the user->item /
-    item->user direction; ``*_T`` are their exact transposes.  Each
-    ring step aggregates through the graph's kernel dispatch ``impl``.
-    Each op's bucket cubes (``arrays``: source rows and row pointers,
-    device arrays row-sharded over the
-    mesh) are built on first use, or up front by ``build`` — the
-    pipeline builds the ops its model uses (``ModelSpec.ring_ops``)
-    before the first trace, so the cubes reach the jitted step as
-    arguments.  A view unflattened over new leaves (``frozen``: the
-    graph argument inside a jitted step) cannot build: an op missing
-    there raises instead of baking host-bucketed cubes into the program.
+    item->user direction, each the other's transpose.  Each ring step
+    aggregates through the graph's kernel dispatch ``impl``.  Each op's
+    bucket cubes (``arrays``: source rows and row pointers, device
+    arrays row-sharded over the mesh) are built on first use, or up
+    front by ``build`` — the pipeline builds ``sym`` before the first
+    trace, so the cubes reach the jitted step as arguments.  A view
+    unflattened over new leaves (``frozen``: the graph argument inside
+    a jitted step) cannot build: an op missing there raises instead of
+    baking host-bucketed cubes into the program.
     """
 
     def __init__(self, shard: ShardPlan, user: np.ndarray, item: np.ndarray,
                  n_users: int, n_items: int, impl: str):
-        from repro.dist.ring_spmm import bucket_edges, make_ring_spmm
-        self._bucket_edges = bucket_edges
-        self._make_ring_spmm = make_ring_spmm
         self.shard = shard
-        self.impl = impl
         self.n_users = int(n_users)
         self.n_items = int(n_items)
         self.part = shard.partition(n_users + n_items)
         self._src_ui = np.asarray(user, np.int64)
         self._dst_ui = np.asarray(item, np.int64) + n_users
         self.arrays: dict[str, tuple] = {}
-        self._fns: dict = {}
+        self._matmul = _ring_matmul(make_ring_spmm(
+            shard.build_mesh(), shard.dp, self.part.n_local, impl=impl))
         self.frozen = False
 
-    def _banded(self) -> bool:
-        s = self.shard.ring_steps
-        return s is not None and s < self.shard.n_shards
-
-    def _canon(self, which: str) -> str:
-        """Unbanded, transposes alias the plain reverses (sym is
-        self-adjoint, ui/iu are mutual transposes)."""
-        if self._banded():
-            return which
-        return {"sym_T": "sym", "ui_T": "iu", "iu_T": "ui"}.get(which, which)
-
-    def _steps(self, key: str) -> int | None:
-        """Banded transposes run over the full ring (see ``_edges``)."""
-        return None if key.endswith("_T") else self.shard.ring_steps
-
-    def _band_kept(self, src: np.ndarray, dst: np.ndarray):
-        """The subset of edges the banded forward actually applies."""
-        p = self.shard.n_shards
-        n_local = self.part.n_local
-        rel = (src // n_local - dst // n_local) % p
-        keep = rel < self.shard.ring_steps
-        return src[keep], dst[keep]
-
     def _edges(self, key: str):
-        """(src, dst) of one canonical op.  Banded ``*_T`` keys are the
-        exact transposes of the banded forwards: the band keeps edge
-        (s, d) by the ring distance of s's owner AHEAD of d's — an
-        asymmetric criterion — so the VJP cannot reuse a banded reverse
-        ring (it would apply a different edge set than A^T).  Instead
-        the transpose buckets the reversed KEPT edges over the full
-        ring."""
+        """(src, dst) of one op over the unified node space."""
         s, d = self._src_ui, self._dst_ui
-        sym_s, sym_d = np.concatenate([s, d]), np.concatenate([d, s])
-        base = {"sym": (sym_s, sym_d), "ui": (s, d), "iu": (d, s)}
-        if key in base:
-            return base[key]
-        ks, kd = self._band_kept(*base[key[:-2]])
-        return kd, ks
+        if key == "sym":
+            return np.concatenate([s, d]), np.concatenate([d, s])
+        return (s, d) if key == "ui" else (d, s)
 
     def build(self, *keys: str) -> None:
         """Bucket the given ops' edges and place the cubes on the mesh,
         each device holding its dst rows.  Concrete even when first
         reached inside a trace, so nothing traced is memoized."""
-        for key in map(self._canon, keys):
+        for key in keys:
             if key in self.arrays:
                 continue
             if self.frozen:
                 raise RuntimeError(
                     f"ring op {key!r} has no bucket cubes in this graph "
                     f"view; build it before tracing (BipartiteCSR."
-                    f"build_ring, ModelSpec.ring_ops)")
-            src_l, indptr, _ = self._bucket_edges(
-                *self._edges(key), self.part.n_pad, self.shard.n_shards,
-                n_steps=self._steps(key))
+                    f"build_ring)")
+            src_l, indptr, _ = bucket_edges(
+                *self._edges(key), self.part.n_pad, self.shard.n_shards)
             sh = jax.sharding.NamedSharding(
                 self.shard.build_mesh(),
                 jax.sharding.PartitionSpec(self.shard.dp, None, None))
@@ -262,23 +232,11 @@ class _RingGraph:
                 self.arrays[key] = tuple(jax.device_put(a, sh)
                                          for a in (src_l, indptr))
 
-    def _ring_fn(self, n_steps):
-        if n_steps not in self._fns:
-            self._fns[n_steps] = self._make_ring_spmm(
-                self.shard.build_mesh(), self.shard.dp, self.part.n_local,
-                n_steps=n_steps, quantize=self.shard.ring_quant,
-                impl=self.impl)
-        return self._fns[n_steps]
-
     def matmul(self, which: str, x):
         """x_pad [n_pad, D] -> A_which x_pad, VJP = the transpose ring."""
-        fwd, bwd = self._canon(which), self._canon(which + "_T")
-        self.build(fwd, bwd)
-        key = ("vjp", self._steps(fwd), self._steps(bwd))
-        if key not in self._fns:
-            self._fns[key] = _ring_matmul(self._ring_fn(key[1]),
-                                          self._ring_fn(key[2]))
-        return self._fns[key](x, self.arrays[fwd], self.arrays[bwd])
+        bwd = _RING_T[which]
+        self.build(which, bwd)
+        return self._matmul(x, self.arrays[which], self.arrays[bwd])
 
     def nbytes(self) -> int:
         """Bytes of the built bucket cubes (the pipeline builds its
@@ -344,12 +302,11 @@ class BipartiteCSR:
                  n_items: int, edge_mask: np.ndarray | None = None,
                  impl: str | None = None, shard: ShardPlan | None = None,
                  hadamard: str = "auto"):
-        # 'ring' is a first-class dispatch value: it forces the sharded
-        # aggregation route (degenerate 1-device ring when no mesh is
-        # given); node-level kernels still need a pallas/xla backend.
-        if impl == "ring" and shard is None:
-            shard = ShardPlan(spmm="ring")
-        self.impl = default_impl() if impl in (None, "ring") else impl
+        if impl not in (None, "pallas", "xla"):
+            raise ValueError(f"impl must be None, 'pallas' or 'xla', got "
+                             f"{impl!r}; force the ring with a ShardPlan "
+                             f"(spmm='ring')")
+        self.impl = impl or default_impl()
         self.shard = shard
         self.spmm = "ring" if (shard is not None and shard.wants_ring) \
             else self.impl
@@ -426,9 +383,9 @@ class BipartiteCSR:
         return g
 
     def build_ring(self, ops) -> None:
-        """Build the ring cubes of the given ops (e.g. ``("sym",
-        "sym_T")``) now, so they ride into jitted steps as arguments;
-        a no-op off the ring dispatch."""
+        """Build the ring cubes of the given ops (``sym``, ``ui``,
+        ``iu``) now, so they ride into jitted steps as arguments; a
+        no-op off the ring dispatch."""
         if self._ring is not None:
             self._ring.build(*ops)
 

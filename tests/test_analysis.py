@@ -385,8 +385,8 @@ def test_expectation_for_maps_config_to_fragments():
     assert set(single.absent) == set(COLLECTIVES)
     sharded = expectation_for(n_shards=4)
     assert {"collective-permute", "all-reduce"} <= set(sharded.contains)
-    int8 = expectation_for(n_shards=4, grads="int8", ring="int8")
-    assert {"s8", "s32", "all-reduce",
+    int8 = expectation_for(n_shards=4, grads="int8")
+    assert {"s32", "all-reduce",
             "collective-permute"} <= set(int8.contains)
     topk = expectation_for(n_shards=4, grads="topk")
     assert "all-gather" in topk.contains

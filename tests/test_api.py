@@ -83,8 +83,7 @@ def test_mesh_cfg_roundtrip_and_coercion():
     equality is structural, not representational)."""
     spec = _smoke_spec().override({"mesh.shape": (4,),
                                    "mesh.axes": ("data",),
-                                   "mesh.spmm": "ring",
-                                   "mesh.ring_steps": 2})
+                                   "mesh.spmm": "ring"})
     assert ExperimentSpec.from_dict(spec.to_dict()) == spec
     assert ExperimentSpec.from_json(spec.to_json()) == spec
     rt = ExperimentSpec.from_json(spec.to_json())
@@ -98,11 +97,11 @@ def test_mesh_cfg_roundtrip_and_coercion():
 def test_mesh_cli_flags_equal_spec_overrides():
     from repro.launch.train import build_arg_parser, spec_from_args
     args = build_arg_parser().parse_args([
-        "--preset", "lightgcn-smoke", "--mesh", "2x2", "--ring-steps", "2",
+        "--preset", "lightgcn-smoke", "--mesh", "2x2",
         "--spmm", "ring", "--ckpt-dir", "/tmp/ck"])
     spec = spec_from_args(args)
     expect = get_preset("lightgcn-smoke").override(
-        {"mesh.shape": (2, 2), "mesh.ring_steps": 2, "mesh.spmm": "ring",
+        {"mesh.shape": (2, 2), "mesh.spmm": "ring",
          "loop.ckpt_dir": "/tmp/ck/lightgcn"})
     assert spec == expect
 

@@ -45,8 +45,7 @@ def test_compression_cfg_roundtrip_and_validation():
     """CompressionCfg is a first-class spec section: exact JSON
     round-trip, defaults equal to the identity, unknown values raise."""
     spec = _smoke(**{"compression.grads": "topk", "compression.frac": 0.05,
-                     "compression.embed_store": "int8",
-                     "compression.ring": "int8"})
+                     "compression.embed_store": "int8"})
     again = ExperimentSpec.from_json(spec.to_json())
     assert again == spec
     assert again.compression.grads == "topk"
@@ -62,20 +61,17 @@ def test_compression_cfg_roundtrip_and_validation():
         CompressionCfg(frac=0.0)
     with pytest.raises(ValueError, match="compression.embed_store"):
         CompressionCfg(embed_store="int4")
-    with pytest.raises(ValueError, match="compression.ring"):
-        CompressionCfg(ring="topk")
 
 
 def test_compression_cli_flags_equal_spec_overrides():
     from repro.launch.train import build_arg_parser, spec_from_args
     args = build_arg_parser().parse_args(
         ["--compress-grads", "int8", "--compress-frac", "0.05",
-         "--embed-store", "int8", "--compress-ring", "int8"])
+         "--embed-store", "int8"])
     spec = spec_from_args(args)
     assert spec.compression.grads == "int8"
     assert spec.compression.frac == 0.05
     assert spec.compression.embed_store == "int8"
-    assert spec.compression.ring == "int8"
 
 
 def test_grad_compressor_validation():
@@ -369,7 +365,7 @@ def test_recommender_serves_from_quantized_store():
 def test_multidevice_compressed_parity_20_steps():
     """Acceptance (1) + (2), 4-device half: on the forced-4-device mesh
     the default config is bit-identical to the exact sharded run, and
-    int8-psum / topk+EF / int8-ring runs track it over 20 steps."""
+    int8-psum / topk+EF runs track it over 20 steps."""
     out = run_with_devices("""
         import numpy as np
         from repro.api import Experiment, build
@@ -391,8 +387,6 @@ def test_multidevice_compressed_parity_20_steps():
         _, topk = run({"compression.grads": "topk",
                        "compression.frac": 0.1})
         np.testing.assert_allclose(topk, exact, rtol=5e-3, atol=2e-3)
-        _, ring = run({"compression.ring": "int8"})
-        np.testing.assert_allclose(ring, exact, rtol=2e-2, atol=5e-3)
         # EF residual stacks are row-sharded over the dp axis
         import jax
         leaf = jax.tree.leaves(r8.state["comp"]["ef"])[0]
@@ -428,40 +422,6 @@ def test_multidevice_int8_combine_lowers_to_integer_allreduce():
         print("HLO_OK")
     """, n=4)
     assert "HLO_OK" in out
-
-
-def test_multidevice_quantized_ring_rotates_int8():
-    """The quantized ring exchange permutes an s8 payload (1/4 wire
-    bytes) and stays within the quantization error bound of the exact
-    ring result."""
-    out = run_with_devices("""
-        import jax, jax.numpy as jnp, numpy as np
-        from repro.dist.ring_spmm import bucket_edges, make_ring_spmm
-        n_dev, n, d, e = 4, 32, 8, 200
-        rng = np.random.default_rng(0)
-        src = rng.integers(0, n, e).astype(np.int32)
-        dst = rng.integers(0, n, e).astype(np.int32)
-        x = rng.standard_normal((n, d)).astype(np.float32)
-        src_l, indptr, per = bucket_edges(src, dst, n, n_dev)
-        mesh = jax.make_mesh((n_dev,), ("data",))
-        exact = make_ring_spmm(mesh, "data", per)
-        quant = make_ring_spmm(mesh, "data", per, quantize=True)
-        args = (jnp.asarray(x), jnp.asarray(src_l), jnp.asarray(indptr))
-        with mesh:
-            ref = np.asarray(jax.jit(exact)(*args))
-            got = np.asarray(jax.jit(quant)(*args))
-            txt = jax.jit(quant).lower(*args).compile().as_text()
-        from repro.analysis.hlo_audit import assert_clean, expect
-        assert_clean(txt, expect("ring-spmm@int8"), where="quantized-ring")
-        # per-element bound: in-degree x scale/2 rounding error
-        a = np.zeros((n, n), np.float32)
-        np.add.at(a, (dst, src), 1.0)
-        scale = np.abs(x).max() / 127.0
-        bound = a.sum(1).max() * scale
-        assert np.abs(got - ref).max() <= bound, np.abs(got - ref).max()
-        print("RING_QUANT_OK")
-    """, n=4)
-    assert "RING_QUANT_OK" in out
 
 
 # ------------------------------------------------------ property tests

@@ -115,14 +115,14 @@ def test_production_mesh_shapes():
     assert "MESH_OK" in out
 
 
-@pytest.mark.parametrize("n,p,e,steps,coeff", [
-    (64, 8, 500, None, True),
-    (64, 8, 500, 3, False),           # banded: drops edges
-    (48, 4, 1, None, True),
-    (16, 4, 0, 2, False),             # empty edge set
-    (96, 2, 300, 1, True),
+@pytest.mark.parametrize("n,p,e", [
+    (64, 8, 500),
+    (64, 4, 333),
+    (48, 4, 1),
+    (16, 4, 0),                       # empty edge set
+    (96, 2, 300),
 ])
-def test_bucket_edges_emits_csr_buckets(n, p, e, steps, coeff):
+def test_bucket_edges_emits_csr_buckets(n, p, e):
     """Each (dst device, ring step) bucket is a CSR over the device's
     local rows: row pointers from 0 to the bucket's edge count, padding
     past it, and exactly the edges a direct selection finds for the
@@ -133,20 +133,16 @@ def test_bucket_edges_emits_csr_buckets(n, p, e, steps, coeff):
     rng = np.random.default_rng(42)
     src = rng.integers(0, n, e).astype(np.int32)
     dst = rng.integers(0, n, e).astype(np.int32)
-    w = rng.standard_normal(e).astype(np.float32) if coeff else None
-    out = bucket_edges(src, dst, n, p, coeff=w, n_steps=steps)
-    src_l, indptr, coeff_l, n_local = out if coeff else (*out[:2], None,
-                                                         out[2])
-    s = p if steps is None else steps
+    src_l, indptr, n_local = bucket_edges(src, dst, n, p)
     assert n_local == n // p
-    assert src_l.shape[:2] == indptr.shape[:2] == (p, s)
+    assert src_l.shape[:2] == indptr.shape[:2] == (p, p)
     assert indptr.shape[2] == n_local + 1
     emax = src_l.shape[2]
     assert emax % 8 == 0 and emax >= max(int(indptr[..., -1].max()), 1)
     rel = (src // n_local - dst // n_local) % p
     kept = 0
     for d in range(p):
-        for k in range(s):
+        for k in range(p):
             ptr = indptr[d, k]
             assert ptr[0] == 0 and np.all(np.diff(ptr) >= 0)
             cnt = int(ptr[-1])
@@ -159,10 +155,7 @@ def test_bucket_edges_emits_csr_buckets(n, p, e, steps, coeff):
             np.testing.assert_array_equal(src_l[d, k, :cnt],
                                           src[sel] % n_local)
             assert not src_l[d, k, cnt:].any()
-            if coeff:
-                np.testing.assert_array_equal(coeff_l[d, k, :cnt], w[sel])
-                assert not coeff_l[d, k, cnt:].any()
-    assert kept == int(np.sum(rel < s))
+    assert kept == e                  # the full ring keeps every edge
 
 
 def test_bucket_edges_rejects_ragged_and_shard_layer_pads():
@@ -223,165 +216,80 @@ def test_ring_dispatch_matches_csr_forward_and_grads():
     assert "RING_CSR_OK" in out
 
 
-def test_banded_ring_matches_dense_on_band_complete_graph():
-    """n_steps < P visits only the n_steps nearest source-owner blocks;
-    on a graph whose every edge source lives within that band of its
-    destination, nothing is dropped and the banded ring must equal the
-    dense product (fp32 tolerance: ring-step summation order)."""
-    out = run_with_devices("""
-        import jax, jax.numpy as jnp, numpy as np
-        from repro.dist.ring_spmm import bucket_edges, make_ring_spmm
-        p, n, d, e, steps = 4, 32, 8, 240, 2
-        per = n // p
-        rng = np.random.default_rng(7)
-        # band-complete: src block is dst block or its ring successor
-        dst = rng.integers(0, n, e).astype(np.int32)
-        off = rng.integers(0, steps, e)
-        sblk = (dst // per + off) % p
-        src = (sblk * per + rng.integers(0, per, e)).astype(np.int32)
-        x = rng.standard_normal((n, d)).astype(np.float32)
-        src_l, indptr, per_l = bucket_edges(src, dst, n, p, n_steps=steps)
-        mesh = jax.make_mesh((p,), ("data",))
-        fn = make_ring_spmm(mesh, "data", per_l, n_steps=steps)
-        out = jax.jit(fn)(jnp.asarray(x), jnp.asarray(src_l),
-                          jnp.asarray(indptr))
-        a = np.zeros((n, n), np.float32)
-        np.add.at(a, (dst, src), 1.0)
-        np.testing.assert_allclose(np.asarray(out), a @ x,
-                                   rtol=2e-4, atol=2e-4)
-        print("BANDED_OK")
-    """, n=4)
-    assert "BANDED_OK" in out
-
-
-def test_banded_ring_gradients_match_dense_banded_operator():
-    """The band-kept edge set is ASYMMETRIC (edge (s, d) is kept by the
-    ring distance of s's owner ahead of d's), so the banded forward is
-    not its own transpose — the custom VJP must apply the transpose of
-    the KEPT edges, not an independently-banded reverse ring.  Pin both
-    forward and gradients against a dense A_band built host-side with
-    the same band rule, on a general (NOT band-complete) graph."""
-    out = run_with_devices("""
-        import jax, jax.numpy as jnp, numpy as np
-        from repro.data import synth
-        from repro.pipeline.shard import ShardPlan
-        from repro.pipeline.sparse import BipartiteCSR
-        p, steps = 4, 2
-        nu, ni = 40, 24                       # N=64, n_local=16
-        data = synth.generate_bipartite(nu, ni, 500, seed=5)
-        plan = ShardPlan(shape=(p,), ring_steps=steps)
-        g = BipartiteCSR(data.user, data.item, nu, ni, shard=plan)
-        # dense banded reference over the unified node space
-        n = nu + ni
-        n_local = n // p
-        s_all = np.concatenate([data.user, data.item + nu])
-        d_all = np.concatenate([data.item + nu, data.user])
-        rel = (s_all // n_local - d_all // n_local) % p
-        keep = rel < steps
-        assert 0 < keep.sum() < len(keep)     # really drops edges
-        a = np.zeros((n, n), np.float32)
-        np.add.at(a, (d_all[keep], s_all[keep]), 1.0)
-        a = jnp.asarray(a)
-        rdu, rdi = g.rsqrt_du, g.rsqrt_di
-        def ref(xu, xi):
-            z = jnp.concatenate([xu * rdu[:, None], xi * rdi[:, None]])
-            h = a @ z
-            return h[:nu] * rdu[:, None], h[nu:] * rdi[:, None]
-        rng = np.random.default_rng(1)
-        xu = jnp.asarray(rng.standard_normal((nu, 8)).astype(np.float32))
-        xi = jnp.asarray(rng.standard_normal((ni, 8)).astype(np.float32))
-        for x, y in zip(g.sym_propagate(xu, xi), ref(xu, xi)):
-            np.testing.assert_allclose(x, y, rtol=2e-4, atol=2e-4)
-        def loss(f):
-            return lambda xu, xi: (jnp.sum(f(xu, xi)[0] ** 2)
-                                   + jnp.sum(f(xu, xi)[1] ** 3))
-        gr = jax.grad(loss(ref), argnums=(0, 1))(xu, xi)
-        gb = jax.grad(loss(g.sym_propagate), argnums=(0, 1))(xu, xi)
-        for x, y in zip(gb, gr):
-            np.testing.assert_allclose(x, y, rtol=2e-4, atol=2e-4)
-        print("BANDED_GRAD_OK")
-    """, n=4)
-    assert "BANDED_GRAD_OK" in out
-
-
-@pytest.mark.parametrize("which,steps", [("sym", None), ("ui", None),
-                                         ("sym", 2)])
-def test_ring_pallas_route_matches_dense(which, steps):
-    """Each ring bucket aggregated by the Pallas gather-SpMM (interpret
-    mode here) inside the ring's shard_map: ``_RingGraph.matmul``'s
-    forward equals the dense ``A @ x`` and its VJP ``A^T @ ct``, on a
-    ragged graph (padded rows), for the symmetric op, one direction,
-    and a banded ring that drops edges; the route is counted."""
+@pytest.mark.parametrize("impl", ["pallas", "xla"])
+@pytest.mark.parametrize("which", ["sym", "ui", "iu"])
+def test_ring_pallas_route_matches_dense(which, impl):
+    """Each ring bucket aggregated by the gather-SpMM of ``impl`` (the
+    Pallas kernel in interpret mode here, or the XLA oracle) inside the
+    ring's shard_map: ``_RingGraph.matmul``'s forward equals the dense
+    ``A @ x`` and its VJP ``A^T @ ct``, on a ragged graph (padded
+    rows), for the symmetric op and each direction; ``ring_route``
+    counts that route and no other."""
     out = run_with_devices(f"""
         import jax, jax.numpy as jnp, numpy as np
         from repro import obs
         from repro.data import synth
         from repro.pipeline.shard import ShardPlan
         from repro.pipeline.sparse import BipartiteCSR
-        p, steps, which = 4, {steps}, "{which}"
+        p, which, impl = 4, "{which}", "{impl}"
         nu, ni = 27, 18                       # N=45, padded to 48
         data = synth.generate_bipartite(nu, ni, 200, seed=11)
-        g = BipartiteCSR(data.user, data.item, nu, ni, impl="pallas",
-                         shard=ShardPlan(shape=(p,), ring_steps=steps))
+        g = BipartiteCSR(data.user, data.item, nu, ni, impl=impl,
+                         shard=ShardPlan(shape=(p,)))
         ring = g._ring
-        n, n_local = ring.part.n_pad, ring.part.n_local
+        n = ring.part.n_pad
         u, i = data.user, data.item + nu
         s_all, d_all = {{"sym": (np.concatenate([u, i]),
                                  np.concatenate([i, u])),
-                        "ui": (u, i)}}[which]
-        rel = (s_all // n_local - d_all // n_local) % p
-        keep = rel < (steps or p)
-        if steps:
-            assert 0 < keep.sum() < len(keep)
+                        "ui": (u, i), "iu": (i, u)}}[which]
         a = np.zeros((n, n), np.float32)
-        np.add.at(a, (d_all[keep], s_all[keep]), 1.0)
+        np.add.at(a, (d_all, s_all), 1.0)
         rng = np.random.default_rng(2)
         x = jnp.asarray(rng.standard_normal((n, 8)).astype(np.float32))
         ct = jnp.asarray(rng.standard_normal((n, 8)).astype(np.float32))
-        before = obs.counters()["ring_route"].get("pallas", 0)
         y, vjp = jax.vjp(lambda v: ring.matmul(which, v), x)
         np.testing.assert_allclose(y, a @ np.asarray(x), rtol=2e-5,
                                    atol=2e-5)
         np.testing.assert_allclose(vjp(ct)[0], a.T @ np.asarray(ct),
                                    rtol=2e-5, atol=2e-5)
-        assert obs.counters()["ring_route"]["pallas"] > before
-        assert "xla" not in obs.counters()["ring_route"]
-        print("RING_PALLAS_OK")
+        routes = obs.counters()["ring_route"]
+        assert routes.get(impl, 0) > 0 and set(routes) == {{impl}}, routes
+        print("RING_ROUTE_OK")
     """, n=4)
-    assert "RING_PALLAS_OK" in out
+    assert "RING_ROUTE_OK" in out
 
 
-def test_weighted_ring_keeps_the_xla_route():
-    """A ring whose edges carry coefficients has no Pallas operand for
-    them, so it aggregates on the XLA route whatever ``impl`` asks, and
-    counts that route; banded, it still equals the dense weighted
-    product over the kept edges."""
-    out = run_with_devices("""
-        import jax, jax.numpy as jnp, numpy as np
-        from repro import obs
-        from repro.dist.ring_spmm import bucket_edges, make_ring_spmm
-        p, n, d, e, steps = 4, 32, 8, 300, 3
-        rng = np.random.default_rng(4)
-        src = rng.integers(0, n, e).astype(np.int32)
-        dst = rng.integers(0, n, e).astype(np.int32)
-        w = rng.standard_normal(e).astype(np.float32)
-        x = rng.standard_normal((n, d)).astype(np.float32)
-        src_l, indptr, coeff_l, per = bucket_edges(src, dst, n, p, coeff=w,
-                                                   n_steps=steps)
-        mesh = jax.make_mesh((p,), ("data",))
-        fn = make_ring_spmm(mesh, "data", per, with_coeff=True,
-                            n_steps=steps, impl="pallas")
-        out = jax.jit(fn)(jnp.asarray(x), jnp.asarray(src_l),
-                          jnp.asarray(indptr), jnp.asarray(coeff_l))
-        keep = (src // per - dst // per) % p < steps
-        a = np.zeros((n, n), np.float32)
-        np.add.at(a, (dst[keep], src[keep]), w[keep])
-        np.testing.assert_allclose(np.asarray(out), a @ x, rtol=2e-4,
-                                   atol=2e-4)
-        assert obs.counters()["ring_route"] == {"xla": 1}
-        print("WEIGHTED_RING_OK")
-    """, n=4)
-    assert "WEIGHTED_RING_OK" in out
+def _removed_band():
+    from repro.api import ExperimentSpec
+    ExperimentSpec().override({"mesh.ring_steps": 2})
+
+
+def _removed_int8_payload():
+    from repro.api import ExperimentSpec
+    d = ExperimentSpec().to_dict()
+    d["compression"]["ring"] = "int8"
+    ExperimentSpec.from_dict(d)
+
+
+def _removed_impl_ring():
+    from repro.pipeline.sparse import BipartiteCSR
+    BipartiteCSR(np.array([0]), np.array([0]), 1, 1, impl="ring")
+
+
+@pytest.mark.parametrize("build,err,match", [
+    (_removed_band, KeyError, "unknown spec field 'ring_steps'"),
+    (_removed_int8_payload, ValueError, r"compression keys \['ring'\]"),
+    (_removed_impl_ring, ValueError, "spmm='ring'"),
+], ids=["band", "int8-payload", "impl"])
+def test_removed_ring_options_fail_loudly(build, err, match):
+    """The ring has one schedule: a spec or graph that still asks for a
+    banded ring, an int8 ring payload, or the ring through ``impl``
+    raises, naming what it asked for, instead of running something
+    else."""
+    import sys as _sys
+    _sys.path.insert(0, "src")
+    with pytest.raises(err, match=match):
+        build()
 
 
 def test_sharded_fit_matches_single_device_trajectory():

@@ -10,7 +10,7 @@ from repro.core import bpr, lightgcn, ngcf
 from repro.core.graph import bipartite_from_numpy
 from repro.data import synth
 from repro.pipeline import (MODELS, BipartiteCSR, PipelineConfig,
-                            build_pipeline, get_model)
+                            ShardPlan, build_pipeline, get_model)
 from repro.runtime.loop import LoopConfig, run_pipeline
 
 
@@ -114,13 +114,13 @@ def test_bipartite_csr_hadamard_validation_and_ring_fallback():
     # 'auto' is the factored term under every dispatch: the ring runs it
     # on the symmetric ring LightGCN uses, and nothing forms [E, D]
     assert route() == ("factored", False)
-    assert route(impl="ring") == ("factored", False)
+    assert route(shard=ShardPlan(spmm="ring")) == ("factored", False)
     assert route(hadamard="fused") == ("fused", False)
     # the ring has no fused gather-multiply-aggregate: 'fused' falls
     # back to the composed route there, and the planner must see that
-    assert route(hadamard="fused", impl="ring") == ("composed", True)
+    assert route(hadamard="fused",
+                 shard=ShardPlan(spmm="ring")) == ("composed", True)
     assert route(hadamard="composed") == ("composed", True)
-    assert ngcf_spec.ring_ops == ("sym", "sym_T")
     assert ngcf_spec.materializes_messages    # static flag stands
     assert not get_model("lightgcn").messages_materialized(
         BipartiteCSR(train.user, train.item, data.n_users, data.n_items,
@@ -318,22 +318,22 @@ def test_graph_is_a_step_argument_not_a_constant():
 
 @pytest.mark.parametrize("arch", sorted(MODELS))
 def test_ring_ops_are_built_before_tracing(arch):
-    """Under the ring dispatch the pipeline builds every ring op its
-    model's forward and VJP apply (ModelSpec.ring_ops) before the first
-    trace: lowering the micro step needs no bucketing inside the trace.
+    """Under the ring dispatch the pipeline builds the symmetric ring op,
+    which every model's forward and VJP apply, before the first trace:
+    lowering the micro step needs no bucketing inside the trace.
     A graph argument missing an op raises rather than baking
     host-bucketed cubes into the program."""
     data, train, _ = _small()
     pipe = build_pipeline(PipelineConfig(arch=arch, embed_dim=8,
                                          microbatch=16, target_batch=16,
-                                         impl="ring"), train)
+                                         spmm="ring"), train)
     assert pipe.g.spmm == "ring"
     u, p, n = pipe._next_target_batch(1, 0)
     pipe._micro_value_and_grad.lower(pipe.init_state()["params"], pipe.g,
                                      *pipe._device_batch(u, p, n))
 
     g = BipartiteCSR(train.user, train.item, data.n_users, data.n_items,
-                     impl="ring")
+                     shard=ShardPlan(spmm="ring"))
     x = jnp.ones((data.n_users, 8), jnp.float32)
     u2i = jax.jit(lambda g, x: g.agg_u2i(x))
     with pytest.raises(RuntimeError, match="before tracing"):

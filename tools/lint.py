@@ -44,9 +44,8 @@ LINT_ROOTS = ("src", "benchmarks")
 # Hadamard contract: fusion_audit's cross-arm message-shape check) and
 # 4-way mesh ('auto' must resolve to the factored route, which rides
 # the symmetric ring)
-HLO_ARMS = (("lightgcn", 1, "none", "none"), ("lightgcn", 4, "none", "none"),
-            ("lightgcn", 4, "int8", "none"), ("lightgcn", 4, "none", "int8"),
-            ("ngcf", 1, "none", "none"), ("ngcf", 4, "none", "none"))
+HLO_ARMS = (("lightgcn", 1, "none"), ("lightgcn", 4, "none"),
+            ("lightgcn", 4, "int8"), ("ngcf", 1, "none"), ("ngcf", 4, "none"))
 
 
 def run_lint(paths: list[str]) -> list:
@@ -81,7 +80,7 @@ def lint_main(args) -> int:
 
 def hlo_main(args) -> int:
     failures: list[str] = []
-    for arch, mesh, grads, ring in HLO_ARMS:
+    for arch, mesh, grads in HLO_ARMS:
         env = dict(os.environ)
         env["PYTHONPATH"] = os.pathsep.join(
             [str(_cli.repo_root() / "src"),
@@ -93,11 +92,11 @@ def hlo_main(args) -> int:
         code = ("import json, sys\n"
                 "from repro.analysis import hlo_audit\n"
                 f"v = hlo_audit.smoke_audit(mesh={mesh}, "
-                f"grads={grads!r}, ring={ring!r}, arch={arch!r})\n"
+                f"grads={grads!r}, arch={arch!r})\n"
                 "print(json.dumps(v))\n")
         proc = subprocess.run([sys.executable, "-c", code],
                               capture_output=True, text=True, env=env)
-        arm = f"arch={arch},mesh={mesh},grads={grads},ring={ring}"
+        arm = f"arch={arch},mesh={mesh},grads={grads}"
         if proc.returncode != 0:
             failures.append(f"[{arm}] audit crashed:\n"
                             + proc.stderr.strip())
